@@ -1,10 +1,12 @@
 """Roofline table generator: experiments/dryrun/*.json -> markdown.
 
-Hardware model (TPU v5e per chip): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI. Terms (per device == per chip, post-SPMD HLO):
-    compute    = flops / 197e12
-    memory     = hbm_bytes / 819e9
-    collective = coll_bytes / 50e9
+Hardware model: the per-chip peaks of ``PEAKS``, keyed by the
+``device_kind`` JAX reports; ``peaks`` raises for a kind it has no numbers
+for. The tables model the ``TARGET_KIND`` chip.
+Terms (per device == per chip, post-SPMD HLO):
+    compute    = flops / peak_flops
+    memory     = hbm_bytes / hbm_bw
+    collective = coll_bytes / ici_bw
 MODEL_FLOPS = 6*N*D (dense) or 6*N_active*D (MoE) per chip for train cells;
 forward-only cells use 2*N*D. The useful-fraction column flags remat/
 replication waste. Usage:
@@ -16,9 +18,23 @@ import argparse
 import glob
 import json
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+# Per-chip peaks by ``jax.Device.device_kind``. TPU v5e (JAX reports it as
+# "TPU v5 lite"), Google Cloud "TPU v5e" system architecture page: 197
+# TFLOP/s bf16, 819 GB/s HBM, 1600 Gbps ICI over 4 links (50 GB/s a link).
+PEAKS = {
+    "TPU v5 lite": {"peak_flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+# The chip the dry-run meshes and the fold model are sized for.
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """The per-chip peak rates of ``device_kind``; raises for a kind with
+    no published numbers here rather than lending it another chip's."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no peak rates for device kind {device_kind!r}; "
+                         f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def model_flops_per_chip(arch: str, shape_name: str, chips: int) -> float:
@@ -48,6 +64,7 @@ def load_rows(mesh_tag: str):
 
 
 def render(mesh_tag: str = "sp", fmt: str = "md"):
+    pk = peaks(TARGET_KIND)
     chips = 256 if mesh_tag == "sp" else 512
     rows = load_rows(mesh_tag)
     out = []
@@ -65,9 +82,9 @@ def render(mesh_tag: str = "sp", fmt: str = "md"):
                        f" — | — | {r['status']} |")
             continue
         h = r["hlo_cost"]
-        ct = h["flops"] / PEAK_FLOPS
-        mt = h["hbm_bytes"] / HBM_BW
-        lt = h["coll_bytes"] / ICI_BW
+        ct = h["flops"] / pk["peak_flops"]
+        mt = h["hbm_bytes"] / pk["hbm_bw"]
+        lt = h["coll_bytes"] / pk["ici_bw"]
         dom = max((("compute", ct), ("memory", mt), ("collective", lt)),
                   key=lambda x: x[1])[0]
         mf = model_flops_per_chip(r["arch"], r["shape"], chips)
@@ -96,7 +113,7 @@ def fold_bytes_moved(slab_bytes: int, chunk_rows: int, num_shards: int,
     (read merged + the post-fold shard slab, write merged). The lazy
     engine instead pays a full stacked re-merge at the NEXT query: read
     all ``num_shards`` slabs, write one. Every fold is re-selection-
-    bound, so bytes/HBM_BW is the floor for the epoch's device time.
+    bound, so bytes / HBM bandwidth is the floor for the epoch's device time.
     """
     chunk_bytes = 9 * chunk_rows
     shard_fold = 2 * slab_bytes + chunk_bytes
@@ -108,7 +125,7 @@ def fold_bytes_moved(slab_bytes: int, chunk_rows: int, num_shards: int,
         "maintain_bytes": maintain,
         "lazy_remerge_bytes": lazy_remerge,
         "epoch_bytes": total,
-        "min_epoch_s": total / HBM_BW,
+        "min_epoch_s": total / peaks(TARGET_KIND)["hbm_bw"],
     }
 
 

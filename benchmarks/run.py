@@ -736,7 +736,7 @@ def bench_roofline_fold_model(smoke: bool = False):
     the absorb/fold bytes-moved model (benchmarks.roofline) for the
     serving engine's maintenance paths, plus the dry-run table row count
     when artifacts exist. ``--only roofline`` runs it standalone."""
-    from benchmarks.roofline import HBM_BW, fold_bytes_moved
+    from benchmarks.roofline import TARGET_KIND, fold_bytes_moved, peaks
     spec = C.MultiSketchSpec(objectives=((C.SUM, 64), (C.COUNT, 64),
                                          (C.thresh(2.0), 64)), seed=0)
     b = C.multisketch_slab_bytes(spec)
@@ -749,7 +749,7 @@ def bench_roofline_fold_model(smoke: bool = False):
                 f"shard_fold_bytes={m['shard_fold_bytes']};"
                 f"maintain_bytes={m['maintain_bytes']};"
                 f"lazy_remerge_bytes={m['lazy_remerge_bytes']};"
-                f"hbm_bw={HBM_BW:g}")
+                f"hbm_bw={peaks(TARGET_KIND)['hbm_bw']:g}")
 
 
 def _registry(smoke: bool):
@@ -794,6 +794,8 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default="BENCH_results.json",
                     help="JSON results path")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     registry = _registry(args.smoke)
     if args.only is not None:
         names = {n for n, _, _ in registry}

@@ -15,17 +15,17 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.configs.registry import get_smoke_config  # noqa: E402
 from repro.launch import steps as St  # noqa: E402
-from repro.launch.mesh import mesh_context  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import model as Mod  # noqa: E402
 from repro.optim import adamw  # noqa: E402
 
 
 def run(compress):
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_smoke_config("qwen2-1.5b")
     key = jax.random.PRNGKey(0)
     opt = adamw.OptConfig(total_steps=60, warmup_steps=2, peak_lr=5e-3)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Mod.init_model(key, cfg)
         step, sh = St.make_train_step(
             cfg, opt, mesh, donate=False,

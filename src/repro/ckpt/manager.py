@@ -194,8 +194,9 @@ class CheckpointManager:
         """Restore the newest intact checkpoint into ``template``'s structure.
         Corrupt/partial checkpoints are skipped (fault tolerance). Returns
         (state, step) or (None, -1)."""
-        for step in reversed(self.list_steps()):
-            state = self.restore_step(step, template, shardings)
-            if state is not None:
-                return state, step
+        with self._lock:   # a concurrent save's prune must not delete every
+            for step in reversed(self.list_steps()):   # listed step first
+                state = self.restore_step(step, template, shardings)
+                if state is not None:
+                    return state, step
         return None, -1

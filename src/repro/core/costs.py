@@ -152,10 +152,13 @@ def pad_cost_table(table: CostTable, q_pad: int) -> CostTable:
 
 def sq_dists(centers, points) -> jnp.ndarray:
     """Squared distances [m, c] via the shared quadratic expansion — the ONE
-    distance formula of both the XLA oracle and the Pallas kernel."""
+    distance formula of both the XLA oracle and the Pallas kernel, both
+    contracted at HIGHEST precision (bf16 operands would be amplified by
+    the expansion's cancellation)."""
     ctr = jnp.asarray(centers, jnp.float32)
     pts = jnp.asarray(points, jnp.float32)
     dots = jax.lax.dot_general(ctr, pts, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     cn2 = jnp.sum(ctr * ctr, axis=1)
     pn2 = jnp.sum(pts * pts, axis=1)
@@ -238,4 +241,4 @@ def exact_service_costs(points, queries: CostQueries,
                                                   for x in table)))
     pw = (jnp.ones(pts.shape[:1], jnp.float32) if point_weights is None
           else jnp.asarray(point_weights, jnp.float32))
-    return values @ pw
+    return jnp.matmul(values, pw, precision=jax.lax.Precision.HIGHEST)

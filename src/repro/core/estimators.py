@@ -39,7 +39,9 @@ def estimate_many(fs, weights, probs, member, segments):
     probs = jnp.asarray(probs, jnp.float32)
     ht = jnp.where(member, 1.0 / jnp.maximum(probs, 1e-30), 0.0)
     contrib = jnp.stack([f(weights) for f in fs]) * ht          # [F, n]
-    return contrib @ jnp.asarray(segments).astype(jnp.float32).T
+    # HIGHEST: the TPU's default f32 matmul rounds contrib to bf16
+    return jnp.matmul(contrib, jnp.asarray(segments).astype(jnp.float32).T,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def exact(f: StatFn, weights, active, segment=None):

@@ -39,7 +39,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core import cap, COUNT, SUM
 from repro.core.multi_sketch import (MultiSketch, MultiSketchSpec,
                                      multisketch_select)
-from repro.launch.mesh import shard_map_compat
 
 
 def _leaf_spec(k: int, cap_frac: float, scheme: str) -> MultiSketchSpec:
@@ -119,11 +118,11 @@ def compressed_grads_fn(compute_grads, mesh, *, axis: str = "pod",
 
         bspec = jax.tree.map(lambda _: P(axis), batch)
         rep = jax.tree.map(lambda _: P(), params)
-        loss, metrics, grads = shard_map_compat(
-            grads_body, mesh,
+        loss, metrics, grads = jax.shard_map(
+            grads_body, mesh=mesh,
             in_specs=(rep, bspec, ),
             out_specs=(P(), P(), rep),
-            axis_names={axis})(params, batch)
+            axis_names={axis}, check_vma=False)(params, batch)
 
         # ---- sm2: fully-manual sampled exchange -------------------------
         flat, treedef = jax.tree_util.tree_flatten(grads)
@@ -164,10 +163,10 @@ def compressed_grads_fn(compute_grads, mesh, *, axis: str = "pod",
             return tuple(out)
 
         specs = tuple(flat_specs)
-        new_flat = shard_map_compat(
-            exchange, mesh,
+        new_flat = jax.shard_map(
+            exchange, mesh=mesh,
             in_specs=(P(),) + specs, out_specs=specs,
-            axis_names=all_axes)(step, *flat)
+            axis_names=all_axes, check_vma=False)(step, *flat)
         grads = jax.tree_util.tree_unflatten(treedef, new_flat)
         return loss, metrics, grads
 

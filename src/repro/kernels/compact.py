@@ -18,7 +18,9 @@ priority pass implemented here:
   the f32 priority row — the merge path's only elementwise full pass.
 
 ``compact_take`` chains this with ``bottomk_select`` (Pallas block-select +
-one top_k merge) to emit gather indices for the compacted slab.
+one top_k merge, or one top_k where the capacity is past the block width:
+``blockselect.select_plan``) to emit gather indices for the compacted
+slab.
 """
 from __future__ import annotations
 
@@ -32,7 +34,8 @@ from jax.experimental import pallas as pl
 from repro.kernels._util import pad_tail, resolve_interpret, round_up
 from repro.kernels.blockselect import bottomk_select
 
-BLOCK = 1024
+LANES = 128
+BLOCK_ROWS = 64   # rows of 128 lanes per grid step (8192 entries)
 _INF = np.float32(np.inf)
 
 
@@ -60,31 +63,31 @@ def retention_priority(sorted_keys, weights, member, keep, interpret=None):
     """
     interpret = resolve_interpret(interpret)
     n = sorted_keys.shape[0]
-    # delta-slab sizing: absorb-time maintenance re-selects over a few
-    # hundred retained slots ((1 + dirty) x capacity) every epoch, not a
-    # streaming batch — fit the block to the input (lane-aligned) instead
-    # of padding every call to the full streaming BLOCK. Splitting the
-    # grid first keeps the pad under one lane-quantum per block (n=1100:
-    # 2 x 640 = 1280 padded rows, vs 2048 when clamping to BLOCK); the
-    # kernel is elementwise and pad rows are sliced off, so sizing never
-    # affects the retained bits.
-    g = -(-max(n, 1) // BLOCK)
-    b = min(BLOCK, round_up(-(-max(n, 1) // g), 128))
-    npad = round_up(max(n, 1), b)
+    # the pass is elementwise: lay the rows out as dense [rows, 128] tiles
+    # (full vreg occupancy) and stream BLOCK_ROWS of them per grid step; a
+    # delta-slab input of a few slab capacities is ONE step, padded to the
+    # (8, 128) tile. Pad rows are sliced off, so sizing never affects the
+    # retained bits.
+    rows = round_up(-(-max(n, 1) // LANES), 8)
+    rb = min(BLOCK_ROWS, rows)
+    rows = round_up(rows, rb)
+    npad = rows * LANES
     sk = pad_tail(sorted_keys.astype(jnp.int32), npad, -1)
     prev = jnp.concatenate([jnp.full((1,), -2, jnp.int32), sk[:-1]])
     w = pad_tail(weights.astype(jnp.float32), npad, 0.0)
     mem = pad_tail(member.astype(jnp.int32), npad, 0)
     kp = pad_tail(keep.astype(jnp.int32), npad, 0)
+    spec = pl.BlockSpec((rb, LANES), lambda i: (i, 0))
     out = pl.pallas_call(
         _priority_kernel,
-        grid=(npad // b,),
-        in_specs=[pl.BlockSpec((b,), lambda i: (i,))] * 5,
-        out_specs=pl.BlockSpec((b,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((npad,), jnp.float32),
+        grid=(rows // rb,),
+        in_specs=[spec] * 5,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=interpret,
-    )(sk, prev, mem, kp, w)
-    return out[:n]
+        name="retention_priority",
+    )(*(x.reshape(rows, LANES) for x in (sk, prev, mem, kp, w)))
+    return out.reshape(-1)[:n]
 
 
 def compact_take(sorted_keys, weights, member, keep, capacity: int,

@@ -23,7 +23,9 @@ from jax.experimental import pallas as pl
 
 from repro.kernels._util import pad_tail, resolve_interpret, round_up
 
-BLOCK_X = 512
+# a 1D operand longer than one block must tile as XLA lays it out on the
+# TPU (1024-element tiles), so both blocks are one such tile
+BLOCK_X = 1024
 BLOCK_Y = 1024
 
 
@@ -68,7 +70,7 @@ def rank_counts(weights, s_h, s_l, active, interpret=None):
     interpret = resolve_interpret(interpret)
     n = weights.shape[0]
     # n <= BLOCK_X fits a (1, 1) grid unpadded; otherwise round up to a
-    # BLOCK_Y multiple (also a BLOCK_X multiple since BLOCK_X | BLOCK_Y).
+    # BLOCK_Y multiple (also a BLOCK_X multiple since BLOCK_X | BLOCK_Y)
     npad = n if n <= BLOCK_X else round_up(n, BLOCK_Y)
     bx = min(BLOCK_X, npad)
     by = min(BLOCK_Y, npad)
@@ -90,5 +92,6 @@ def rank_counts(weights, s_h, s_l, active, interpret=None):
         out_shape=[jax.ShapeDtypeStruct((npad,), jnp.int32),
                    jax.ShapeDtypeStruct((npad,), jnp.int32)],
         interpret=interpret,
+        name="rank_counts",
     )(w32, sh32, sl32, a32, w32, sh32, sl32, a32)
     return h[:n], l[:n]

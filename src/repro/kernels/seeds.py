@@ -65,7 +65,9 @@ def _seeds_kernel(keys_ref, w_ref, act_ref, *out_refs, objectives,
     c2 = np.uint32((seed * 0x85EBCA6B + 1) & 0xFFFFFFFF)
     h = _mix(k + c1)
     h = _mix(h ^ c2)
-    u = (h >> np.uint32(8)).astype(jnp.float32) * np.float32(1.0 / (1 << 24))
+    # Mosaic has no uint32 -> f32 cast; the 24-bit value is exact in int32
+    u = ((h >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+         * np.float32(1.0 / (1 << 24)))
     u = u + np.float32(0.5 / (1 << 24))
     r = -jnp.log1p(-u) if scheme == "ppswor" else u
     for j, (kind, param) in enumerate(objectives):
@@ -109,6 +111,7 @@ def _fused_seeds(keys, weights, active, objectives, scheme, seed,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="fused_seeds",
     )(keys, weights, act)
     if want_fvals:
         return outs[0][:, :n], outs[1][:, :n]

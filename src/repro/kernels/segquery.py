@@ -35,7 +35,9 @@ from repro.core.predicates import FLAG_ON_HASH, PRED_COLS
 from repro.kernels._util import pad_tail, resolve_interpret, round_up
 from repro.kernels.seeds import _fval, _mix
 
-BLOCK = 512       # slab slots per grid step
+# slab slots per grid step; a 1D operand longer than one block must tile
+# as XLA lays it out on the TPU (1024-element tiles)
+BLOCK = 1024
 _LANES = 128      # predicate-batch padding quantum
 _SUBLANES = 8     # objective-axis padding quantum
 _GOLDEN = np.uint32(0x9E3779B9)
@@ -77,6 +79,7 @@ def _segquery_kernel(keys_ref, w_ref, p_ref, m_ref, pred_ref, out_ref, *,
 
     out_ref[...] += jax.lax.dot_general(
         contrib, sel, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,            # f32, not bf16
         preferred_element_type=jnp.float32)             # [nf_pad, B]
 
 
@@ -124,5 +127,6 @@ def segment_query_slab(keys, weights, probs, member, table, objectives,
         out_specs=pl.BlockSpec((nf_pad, bpad), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((nf_pad, bpad), jnp.float32),
         interpret=interpret,
+        name="segment_query",
     )(k, w, p, m, t)
     return out[:nf, :b]

@@ -117,7 +117,7 @@ class ClusterEngine:
 
     def __init__(self, dim: int, k: int = 64, mu: float = 2.0,
                  n_anchors: int = 8, scheme: str = "ppswor", seed: int = 0,
-                 chunk: int = 256, q_quantum: int = 16, q_max: int = 128,
+                 chunk: int = 256, q_quantum: int = 16,
                  use_kernels: Optional[bool] = None):
         self.dim = int(dim)
         self.k = int(k)
@@ -125,7 +125,6 @@ class ClusterEngine:
         self.n_anchors = int(n_anchors)
         self.chunk = int(chunk)
         self.q_quantum = int(q_quantum)
-        self.q_max = int(q_max)   # per-launch Q ceiling (kernel VMEM budget)
         self.use_kernels = use_kernels
         self._handed_out = False  # sample() gave away live slab buffers
         self.spec = MultiSketchSpec(objectives=((SUM, self.k),),
@@ -260,8 +259,7 @@ class ClusterEngine:
             config={"dim": self.dim, "k": self.k, "mu": self.mu,
                     "n_anchors": self.n_anchors,
                     "scheme": self.spec.scheme, "seed": self.spec.seed,
-                    "chunk": self.chunk, "q_quantum": self.q_quantum,
-                    "q_max": self.q_max})
+                    "chunk": self.chunk, "q_quantum": self.q_quantum})
 
     @classmethod
     def from_handoff(cls, replica: "ClusterReplica",
@@ -284,24 +282,18 @@ class ClusterEngine:
     def service_costs(self, queries) -> np.ndarray:
         """HT clustering-cost / ball-density estimates for a Q-batch of
         service-cost queries -> float numpy [Q]. ONE fused launch over the
-        slab per ``q_max`` rows regardless of Cmax (kernels.servicecost —
-        its [Q*Cmax, 128] distance block must fit VMEM, so oversize batches
-        are split); Q pads to ``q_quantum`` with null rows so same-bucket
-        batches share one compiled executable."""
+        slab regardless of Q and Cmax (kernels.servicecost tiles Q inside
+        the launch, so its VMEM footprint is bounded for any batch); Q pads
+        to ``q_quantum`` with null rows so same-bucket batches share one
+        compiled executable."""
         table = encode_cost_queries(queries)
         table = CostTable(*(np.asarray(x) for x in table))
         q = table.mu.shape[0]
-        out = np.empty((q,), np.float32)
-        for s in range(0, q, self.q_max):
-            part = CostTable(*(x[s:s + self.q_max] for x in table))
-            qp = part.mu.shape[0]
-            qpad = max(self.q_quantum,
-                       -(-qp // self.q_quantum) * self.q_quantum)
-            est = estimate_service_costs(
-                self._coords, self._sketch.probs, self._sketch.member,
-                pad_cost_table(part, qpad), use_kernels=self.use_kernels)
-            out[s:s + qp] = np.asarray(est)[:qp]
-        return out
+        qpad = max(self.q_quantum, -(-q // self.q_quantum) * self.q_quantum)
+        est = estimate_service_costs(
+            self._coords, self._sketch.probs, self._sketch.member,
+            pad_cost_table(table, qpad), use_kernels=self.use_kernels)
+        return np.asarray(est)[:q]
 
     def clustering_cost(self, centers, mu: Optional[float] = None) -> float:
         """Estimated Sum_x min_{c in centers} d(x,c)^mu for ONE set."""

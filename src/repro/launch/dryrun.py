@@ -48,7 +48,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     from repro.configs.registry import get_config, sub_quadratic
     from repro.configs.shapes import SHAPES, cell_is_runnable
     from repro.launch import hlo_cost, steps as St
-    from repro.launch.mesh import make_production_mesh, mesh_context
+    from repro.launch.mesh import make_production_mesh
     from repro.optim import adamw
 
     cfg = get_config(arch)
@@ -73,7 +73,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     if microbatch == 0 and shape.kind == "train":
         microbatch = DEFAULT_MICROBATCH.get(arch, 1)
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = adamw.OptConfig()
             step, _ = St.make_train_step(

@@ -4,80 +4,37 @@ Functions (not module constants) so importing never touches jax device state.
 Single pod: (16, 16) = 256 chips, axes ("data", "model").
 Multi-pod:  (2, 16, 16) = 512 chips, axes ("pod", "data", "model") — the
 "pod" axis is an outer data-parallel axis whose collectives cross DCN.
+
+Every mesh the program builds goes through :func:`make_mesh`, which gives
+its axes the *Auto* type: the code shards through in/out shardings and
+lets the partitioner propagate them, so a gather from a sharded array
+(an embedding lookup, a per-shard row of a stacked slab) needs no
+``out_sharding``. ``jax.make_mesh`` defaults to *Explicit* axes, which
+refuse such gathers.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
+from jax.sharding import AxisType
 
 
-@contextlib.contextmanager
-def mesh_context(mesh):
-    """Version-compatible ambient-mesh context.
-
-    ``jax.set_mesh`` (the context-manager form) only exists in newer jax;
-    on older versions the legacy ``Mesh.__enter__`` resource context is the
-    equivalent. All drivers/tests enter meshes through this helper so the
-    repo runs on both. Explicit NamedShardings built from ``mesh`` keep
-    working either way — the ambient mesh only backs convenience APIs.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        with set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None,
-                     check=False):
-    """Version-compatible ``shard_map``.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=, check_vma=)``; older
-    versions have ``jax.experimental.shard_map.shard_map(..., auto=,
-    check_rep=)`` where ``auto`` is the complement of ``axis_names``. The
-    repo's manual-collective code (distopt, sharded summaries) goes through
-    this shim so both APIs work.
-    """
-    names = frozenset(axis_names) if axis_names else frozenset(mesh.axis_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=names,
-                             check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if (names != frozenset(mesh.axis_names)
-            and not jax.config.jax_use_shardy_partitioner):
-        # Partially-manual regions crash the legacy GSPMD partitioner
-        # (hlo_sharding_util IsManualSubgroup check); the code targets sdy
-        # semantics, which old jax only applies behind this flag. The flag
-        # must still be set when the wrapped fn COMPILES (not just traces),
-        # so it cannot be scoped to this call — flip it process-wide, once,
-        # loudly. New jax (jax.shard_map present) never takes this path.
-        import warnings
-        warnings.warn(
-            "shard_map_compat: enabling jax_use_shardy_partitioner "
-            "process-wide — legacy jax cannot partition partially-manual "
-            "shard_map regions under GSPMD; subsequent jit compilations "
-            "in this process will use the shardy partitioner.",
-            stacklevel=2)
-        jax.config.update("jax_use_shardy_partitioner", True)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check, auto=frozenset(mesh.axis_names) - names)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axis types (see module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Small mesh over the locally available devices (tests/examples)."""
     n = len(jax.devices())
     data = n // model_axis
-    return jax.make_mesh((data, model_axis), ("data", "model"))
+    return make_mesh((data, model_axis), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

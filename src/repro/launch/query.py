@@ -332,7 +332,13 @@ class SegmentQueryEngine:
         from ``launch.summary.sharded_multisketch_shards``) as the resident
         state — the merge stays lazy until the first query. Wholesale
         replacement: the merged-slab cache is dropped (full path next) and
-        the adopted layout becomes the new un-truncatable base layout."""
+        the adopted layout becomes the new un-truncatable base layout.
+
+        The engine is resident on one device: rows that arrive spread
+        over a mesh (one per device) are brought to that device first —
+        the folds' Pallas kernels cannot be partitioned across devices."""
+        stacked = jax.device_put(
+            stacked, next(iter(self._empty.keys.devices())))
         m = stacked.keys.shape[0]
         self._shards = [jax.tree.map(lambda x, i=i: x[i], stacked)
                         for i in range(m)]

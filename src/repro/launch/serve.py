@@ -23,7 +23,7 @@ import numpy as np
 from repro.configs.registry import get_config, get_smoke_config, list_archs
 from repro.core import (EVERYTHING, SUM, COUNT, MultiSketchSpec,
                         hash_fraction, thresh)
-from repro.launch.mesh import make_host_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh
 from repro.launch.pool import EnginePool
 from repro.models import model as Mod
 
@@ -54,7 +54,7 @@ def main(argv=None):
     key = jax.random.PRNGKey(args.seed)
     max_len = args.prompt_len + args.gen
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Mod.init_model(key, cfg)
         prompts = jax.random.randint(key, (args.batch, args.prompt_len),
                                      0, cfg.vocab_size)

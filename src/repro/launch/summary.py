@@ -32,7 +32,6 @@ from repro.core.multi_sketch import (MultiSketch, MultiSketchSpec,
                                      multisketch_build,
                                      multisketch_finalize,
                                      multisketch_merge_stacked)
-from repro.launch.mesh import shard_map_compat
 
 
 def sharded_multisketch(spec: MultiSketchSpec, mesh, keys, weights,
@@ -57,12 +56,12 @@ def sharded_multisketch(spec: MultiSketchSpec, mesh, keys, weights,
         return multisketch_merge_stacked(spec, MultiSketch(*gathered),
                                          use_kernels=False)
 
-    # fully manual (all axes): the off-``axis`` axes just see replicated
-    # data, and legacy-jax shard_map needs no auto-axis support that way
-    fn = shard_map_compat(
-        local, mesh,
+    # fully manual (all axes): the off-``axis`` axes just see replicated data
+    fn = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=jax.tree.map(lambda _: P(), multisketch_shape(spec)))
+        out_specs=jax.tree.map(lambda _: P(), multisketch_shape(spec)),
+        check_vma=False)
     # re-finalize at host level: the in-trace finalize inlined into the
     # shard_map program, and canonical prob bits require the one
     # fixed-shape finalizer program (core.multi_sketch)
@@ -89,10 +88,11 @@ def sharded_multisketch_shards(spec: MultiSketchSpec, mesh, keys, weights,
         sk = multisketch_build(spec, k, w, a, use_kernels=False)
         return jax.tree.map(lambda x: x[None], sk)  # [1, ...] rows to stack
 
-    fn = shard_map_compat(
-        local, mesh,
+    fn = jax.shard_map(
+        local, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=jax.tree.map(lambda _: P(axis), multisketch_shape(spec)))
+        out_specs=jax.tree.map(lambda _: P(axis), multisketch_shape(spec)),
+        check_vma=False)
     return jax.jit(fn)(keys, weights, active)
 
 

@@ -26,7 +26,7 @@ from repro.core import (COUNT, SUM, MultiSketchSpec, multisketch_empty,
                         sketch_estimate, thresh)
 from repro.data.pipeline import DataConfig, Loader, SyntheticCorpus
 from repro.launch import steps as St
-from repro.launch.mesh import make_host_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.models import model as Mod
 from repro.optim import adamw
 
@@ -37,7 +37,7 @@ def parse_mesh(spec: str):
     dims = tuple(int(x) for x in spec.split("x"))
     names = {1: ("data",), 2: ("data", "model"),
              3: ("pod", "data", "model")}[len(dims)]
-    return jax.make_mesh(dims, names)
+    return make_mesh(dims, names)
 
 
 def main(argv=None):
@@ -76,7 +76,7 @@ def main(argv=None):
         objectives=((SUM, 64), (COUNT, 64), (thresh(5.0), 64)), seed=1234)
     mgr = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         step_fn, st_sh = St.make_train_step(
             cfg, opt_cfg, mesh, donate=False,
             microbatch=args.microbatch or None,

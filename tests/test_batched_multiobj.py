@@ -83,6 +83,25 @@ def test_batched_bottomk_select_matches_ref(rng, n, k):
     np.testing.assert_array_equal(np.asarray(t), np.asarray(rt))
 
 
+@pytest.mark.parametrize("n,k,plan", [(4096, 200, "block"),
+                                      (4096, 2047, "top_k"),
+                                      (600, 600, "top_k"), (300, 40, "block")])
+def test_select_plan_by_size(rng, n, k, plan):
+    """The select plan is picked from the sizes alone (the block plan only
+    while a block's lane-padded candidate row is narrower than the block),
+    and both plans return the oracle's bits, ties included."""
+    from repro.kernels.blockselect import select_plan
+    assert select_plan(n, min(k + 1, n)) == plan
+    seeds = rng.exponential(1.0, (3, n)).astype(np.float32)
+    seeds[:, ::7] = seeds[:, 3:4]                    # many exact ties
+    seeds[rng.random((3, n)) > 0.9] = np.inf
+    v, i, t = K.batched_bottomk_select(jnp.asarray(seeds), k)
+    rv, ri, rt = R.batched_bottomk_select_ref(seeds, k)
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(rv))
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
+    np.testing.assert_array_equal(np.asarray(t), np.asarray(rt))
+
+
 @pytest.mark.parametrize("n", [1024, 1500])
 def test_fused_seeds_fvals_matches_ref(rng, n):
     keys, w, act = _data(rng, n)

@@ -193,17 +193,24 @@ def test_cluster_engine_sample_survives_absorb():
 
 
 def test_service_costs_q_chunking_matches_one_shot():
-    """Q past the per-launch ceiling is split transparently; estimates
-    match the unchunked XLA batch."""
+    """A batch past one Q-tile of the kernel (several tiles in ONE launch)
+    matches the unchunked XLA batch."""
+    from repro.kernels.servicecost import _q_tile
     eng = _engine("ppswor")
-    eng.q_max = 32
     table = _queries(150, 2.0)
+    assert _q_tile(150, 64) < 150
     got = eng.service_costs(table)
     pts, probs, member = eng.sample()
     want = np.asarray(C.estimate_service_costs(pts, probs, member, table,
                                                use_kernels=False))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=1e-3)
-    eng.q_max = 128
+    wide = encode_cost_queries(
+        [cost_query(np.repeat(c[:1], 64, axis=0), 2.0) for c in
+         np.asarray(table.centers)[:150]])
+    got_w = service_cost_slab(pts, probs, member, wide)
+    want_w = np.asarray(C.estimate_service_costs(pts, probs, member, wide,
+                                                 use_kernels=False))
+    np.testing.assert_allclose(got_w, want_w, rtol=2e-4, atol=1e-3)
 
 
 def test_cluster_engine_explicit_keys_never_collide_with_default():

@@ -20,14 +20,14 @@ _SCRIPT = textwrap.dedent("""
     from repro.launch import sharding as Sh
     from repro.optim import adamw
     from repro.models import model as Mod
-    from repro.launch.mesh import mesh_context
+    from repro.launch.mesh import make_mesh
 
     out = {}
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_smoke_config("qwen2-1.5b")
     key = jax.random.PRNGKey(0)
     opt = adamw.OptConfig(total_steps=50, warmup_steps=2, peak_lr=5e-3)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Mod.init_model(key, cfg)
         batch = {"tokens": jax.random.randint(key, (8, 32), 0,
                                               cfg.vocab_size)}
@@ -93,8 +93,9 @@ def test_microbatch_matches_dense_loss(multi_device_result):
 def test_partition_rules_divisibility():
     """Non-divisible dims must be replicated, divisible sharded."""
     import jax
+    from repro.launch.mesh import make_mesh
     from repro.launch.sharding import logical_to_pspec
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         shape = {"model": 16, "data": 16}

@@ -195,8 +195,12 @@ def test_incremental_epoch_dispatches_delta_fold_only(monkeypatch):
 def test_delta_fold_launch_count_flat_in_dirty_shards(m):
     """The kernel-path delta fold is a fixed 4-launch chain (fused seeds,
     block-select, retention-priority, compacting block-select) regardless
-    of how many dirty slabs ride in the delta."""
-    spec = C.MultiSketchSpec(objectives=_objectives(3), seed=0)
+    of how many dirty slabs ride in the delta. The capacity puts every
+    delta size in the block plan of both selects
+    (``blockselect.select_plan``); smaller folds swap a select for one
+    XLA top_k, which ``test_select_plan_by_size`` covers."""
+    spec = C.MultiSketchSpec(objectives=_objectives(3), seed=0,
+                             capacity=256)
     keys, w = _data(n=900, seed=9)
     base = C.multisketch_build(spec, keys, w)
     parts = [C.multisketch_build(spec, np.arange(10_000 * (i + 1),

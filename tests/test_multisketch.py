@@ -118,6 +118,22 @@ def test_inactive_duplicate_does_not_shadow_observation():
     assert float(np.asarray(st.weights)[m][0]) == 3.0
 
 
+@pytest.mark.parametrize("use_kernels", [True, False])
+def test_negative_zero_weight_does_not_shadow_observation(use_kernels):
+    """Regression: a valid -0.0 observation of a key (quarantine admits
+    it: -0.0 < 0 is False) must not sort ahead of the key's positive
+    observation in the dedup order and drop its weight."""
+    spec = C.MultiSketchSpec(objectives=((C.SUM, 4),), seed=0)
+    st = C.multisketch_empty(spec)
+    st = C.multisketch_absorb(st, np.array([7, 7, 9]),
+                              np.array([-0.0, 3.0, 1.0], np.float32),
+                              spec=spec, use_kernels=use_kernels)
+    got_w = {int(k): float(v) for k, v, ok in
+             zip(np.asarray(st.keys), np.asarray(st.weights),
+                 np.asarray(st.member)) if ok}
+    assert got_w == {7: 3.0, 9: 1.0}
+
+
 def test_xla_and_kernel_paths_identical():
     keys, w = _data(n=2048, seed=5)
     objs = _objectives(3)
@@ -221,13 +237,14 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     import repro.core as C
+    from repro.launch.mesh import make_mesh
     from repro.launch.summary import sharded_multisketch
 
     rng = np.random.default_rng(4)
     n = 4096
     keys = rng.permutation(np.arange(n)).astype(np.int32)
     w = rng.lognormal(0, 1.5, n).astype(np.float32)
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     out = {}
     for nf, objs in (
             (1, ((C.SUM, 16),)),

@@ -219,6 +219,7 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     import numpy as np
     import jax
     import repro.core as C
+    from repro.launch.mesh import make_mesh
     from repro.launch.summary import sharded_multisketch
     from repro.launch.query import SegmentQueryEngine
 
@@ -226,7 +227,7 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     n = 4096
     keys = rng.permutation(np.arange(n)).astype(np.int32)
     w = rng.lognormal(0, 1.5, n).astype(np.float32)
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     spec = C.MultiSketchSpec(objectives=((C.SUM, 16), (C.COUNT, 8),
                                          (C.thresh(2.0), 12)), seed=13)
     eager = sharded_multisketch(spec, mesh, keys, w)
@@ -237,14 +238,19 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     est = eng.query_many(predicates=[C.EVERYTHING,
                                      C.key_range(0, n // 2 - 1)])
     ok_est = abs(est[0, 0] / w.sum() - 1) < 0.5
+    # the engine is resident on ONE device: its folds' Pallas kernels
+    # cannot be partitioned over the mesh the rows were built on
+    one_device = len(lazy.keys.devices()) == 1
     print("RESULT " + json.dumps({"same": bool(same),
-                                  "est_ok": bool(ok_est)}))
+                                  "est_ok": bool(ok_est),
+                                  "one_device": one_device}))
 """)
 
 
 def test_engine_from_sharded_matches_eager_multidevice():
     """Lazy merge-on-demand over real per-device shards is bit-identical
-    to the eager replicated sharded_multisketch re-selection."""
+    to the eager replicated sharded_multisketch re-selection, and runs on
+    one device."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
     env.pop("XLA_FLAGS", None)
@@ -255,7 +261,8 @@ def test_engine_from_sharded_matches_eager_multidevice():
     assert r.returncode == 0, r.stderr[-3000:]
     line = [l for l in r.stdout.splitlines() if l.startswith("RESULT ")][-1]
     assert json.loads(line[len("RESULT "):]) == {"same": True,
-                                                 "est_ok": True}
+                                                 "est_ok": True,
+                                                 "one_device": True}
 
 
 # ------------------------------------------------ satellites

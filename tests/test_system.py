@@ -11,7 +11,7 @@ from repro.ckpt.manager import CheckpointManager
 from repro.configs.registry import get_smoke_config
 from repro.data.pipeline import DataConfig, Loader, SyntheticCorpus
 from repro.launch import steps as St
-from repro.launch.mesh import make_host_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.models import model as Mod
 from repro.optim import adamw
 from repro.telemetry.stats import StatsCollector, TelemetryConfig
@@ -28,7 +28,7 @@ def _setup(arch="qwen2-1.5b", steps=60):
 def test_training_reduces_loss():
     cfg, mesh, opt = _setup()
     key = jax.random.PRNGKey(0)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Mod.init_model(key, cfg)
         step, sh = St.make_train_step(cfg, opt, mesh, donate=False)
         state = jax.device_put(
@@ -46,7 +46,7 @@ def test_training_reduces_loss():
 def test_microbatch_equivalent_loss():
     cfg, mesh, opt = _setup()
     key = jax.random.PRNGKey(0)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Mod.init_model(key, cfg)
         batch = {"tokens": jax.random.randint(key, (8, 32), 0,
                                               cfg.vocab_size)}
@@ -65,7 +65,7 @@ def test_checkpoint_save_restore_resume(tmp_path):
     cfg, mesh, opt = _setup()
     key = jax.random.PRNGKey(0)
     mgr = CheckpointManager(str(tmp_path), keep=2)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Mod.init_model(key, cfg)
         step, sh = St.make_train_step(cfg, opt, mesh, donate=False)
         state = jax.device_put(
@@ -87,7 +87,7 @@ def test_checkpoint_corruption_falls_back(tmp_path):
     cfg, mesh, opt = _setup()
     key = jax.random.PRNGKey(0)
     mgr = CheckpointManager(str(tmp_path), keep=3)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Mod.init_model(key, cfg)
         state = {"params": params, "opt": adamw.init_opt_state(params)}
         mgr.save(1, state, blocking=True)
@@ -106,7 +106,7 @@ def test_keep_k_pruning(tmp_path):
     cfg, mesh, opt = _setup()
     mgr = CheckpointManager(str(tmp_path), keep=2)
     key = jax.random.PRNGKey(0)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params, _ = Mod.init_model(key, cfg)
         state = {"params": params, "opt": adamw.init_opt_state(params)}
         for s in (1, 2, 3, 4):
@@ -156,14 +156,14 @@ def test_elastic_restart_reshards(tmp_path):
     """Checkpoint on one mesh restores onto a different mesh."""
     cfg, _, opt = _setup()
     key = jax.random.PRNGKey(0)
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = make_mesh((1, 1), ("data", "model"))
     mgr = CheckpointManager(str(tmp_path))
-    with mesh_context(mesh1):
+    with jax.set_mesh(mesh1):
         params, _ = Mod.init_model(key, cfg)
         state = {"params": params, "opt": adamw.init_opt_state(params)}
         mgr.save(5, state, blocking=True)
     mesh2 = make_host_mesh()  # possibly different shape
-    with mesh_context(mesh2):
+    with jax.set_mesh(mesh2):
         step, sh = St.make_train_step(cfg, opt, mesh2, donate=False)
         restored, rstep = mgr.restore_latest(state, sh)
         assert rstep == 5
