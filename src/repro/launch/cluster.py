@@ -47,6 +47,7 @@ from repro.core.metric_domains import (anchor_upper_weights,
                                        farthest_point_anchors)
 from repro.core.multi_sketch import (MultiSketchSpec, multisketch_absorb,
                                      multisketch_empty, pad_chunk)
+from repro.telemetry import trace
 
 
 def _sorted_lookup(cand_keys, cand_coords, queries):
@@ -136,11 +137,6 @@ class ClusterEngine:
         self._norm = None            # frozen per-anchor column sums
         self._epoch = 0
         self._next_key = 0
-        # natively absorb-time maintained: the fold + coords realignment
-        # land in the SAME epoch, so queries never pay merge work — the
-        # counters mirror SegmentQueryEngine.merge_stats for telemetry
-        self.merge_stats = {"absorb_time": 0, "bytes_resident": 0}
-        self._update_gauges()
 
     @classmethod
     def fit(cls, X, **kw) -> "ClusterEngine":
@@ -211,14 +207,6 @@ class ClusterEngine:
             self._sketch.keys, old_keys, old_coords,
             jnp.asarray(keys, jnp.int32), Ppad)
         self._epoch += 1
-        self.merge_stats["absorb_time"] += 1
-        self._update_gauges()
-
-    def _update_gauges(self):
-        """Device residency gauge (host-side, no sync): slab + coords."""
-        self.merge_stats["bytes_resident"] = (
-            sum(int(getattr(x, "nbytes", 0)) for x in self._sketch)
-            + int(getattr(self._coords, "nbytes", 0)))
 
     def sample(self):
         """(coords [cap, dim], probs [cap], member [cap]) — the resident
@@ -275,7 +263,6 @@ class ClusterEngine:
         eng._norm = cp(replica.norm)
         eng._next_key = int(replica.next_key)
         eng._epoch = int(replica.epoch)
-        eng._update_gauges()
         return eng
 
     # -- fused batched queries ---------------------------------------------
@@ -285,15 +272,32 @@ class ClusterEngine:
         slab regardless of Q and Cmax (kernels.servicecost tiles Q inside
         the launch, so its VMEM footprint is bounded for any batch); Q pads
         to ``q_quantum`` with null rows so same-bucket batches share one
-        compiled executable."""
-        table = encode_cost_queries(queries)
-        table = CostTable(*(np.asarray(x) for x in table))
-        q = table.mu.shape[0]
-        qpad = max(self.q_quantum, -(-q // self.q_quantum) * self.q_quantum)
-        est = estimate_service_costs(
-            self._coords, self._sketch.probs, self._sketch.member,
-            pad_cost_table(table, qpad), use_kernels=self.use_kernels)
-        return np.asarray(est)[:q]
+        compiled executable.
+
+        Under a profiler capture it records the spans ``cluster.score``
+        (the call), ``.prep`` (encode and pad), ``.dispatch`` (the
+        estimate's host-to-device hand-off and launch) and ``.wait`` (the
+        read-back, which blocks on the kernel), and the counters
+        ``cluster.score.sets`` (Q) and ``.table_bytes`` (the padded
+        table's five fields)."""
+        with trace.span("cluster.score"):
+            with trace.span("cluster.score.prep"):
+                table = encode_cost_queries(queries)
+                table = CostTable(*(np.asarray(x) for x in table))
+                q = table.mu.shape[0]
+                qpad = max(self.q_quantum,
+                           -(-q // self.q_quantum) * self.q_quantum)
+                table = pad_cost_table(table, qpad)
+            with trace.span("cluster.score.dispatch"):
+                est = estimate_service_costs(
+                    self._coords, self._sketch.probs, self._sketch.member,
+                    table, use_kernels=self.use_kernels)
+            with trace.span("cluster.score.wait"):
+                out = np.asarray(est)[:q]
+        trace.count("cluster.score.sets", q)
+        trace.count("cluster.score.table_bytes",
+                    lambda: sum(x.nbytes for x in table))
+        return out
 
     def clustering_cost(self, centers, mu: Optional[float] = None) -> float:
         """Estimated Sum_x min_{c in centers} d(x,c)^mu for ONE set."""
@@ -356,34 +360,49 @@ def local_search(engine: ClusterEngine, k: int, mu: Optional[float] = None,
     relatively. ``scorer`` defaults to the engine's fused HT estimator;
     pass :func:`exact_scorer` to run the identical search on ground-truth
     costs.
+
+    Under a profiler capture it records the spans ``cluster.search`` (the
+    call), ``.seed`` (candidates, farthest-point init, first score),
+    ``.round`` (one swap round), ``.build`` (the round's swap sets) and
+    ``.encode`` (their cost table).
     """
     mu = engine.mu if mu is None else float(mu)
     if scorer is None:
         scorer = engine.service_costs
-    cand = _candidate_pool(engine, n_cand)
-    ncand = cand.shape[0]
-    k = min(k, ncand)
-    # deterministic k-center init over the candidate pool
-    init_idx, _ = farthest_point_anchors(jnp.asarray(cand), k)
-    cur = np.asarray(cand)[np.asarray(init_idx)]              # [k, dim]
-
-    history = [float(np.asarray(scorer(cost_table(cur[None], mu)))[0])]
-    for _ in range(rounds):
-        # row 0: current set; row 1 + i*ncand + j: swap center i -> cand j
-        sets = np.broadcast_to(cur, (k * ncand, k, cur.shape[1])).copy()
-        sets = sets.reshape(k, ncand, k, -1)
-        for i in range(k):
-            sets[i, :, i, :] = cand
-        batch = np.concatenate([cur[None], sets.reshape(k * ncand, k, -1)])
-        scores = np.asarray(scorer(cost_table(batch, mu)))
-        best = int(np.argmin(scores[1:])) + 1
-        if scores[best] < scores[0] * (1.0 - tol):
-            i, j = divmod(best - 1, ncand)
-            cur = cur.copy()
-            cur[i] = cand[j]
-            history.append(float(scores[best]))
-        else:
-            break
+    with trace.span("cluster.search"):
+        with trace.span("cluster.search.seed"):
+            cand = _candidate_pool(engine, n_cand)
+            ncand = cand.shape[0]
+            k = min(k, ncand)
+            # deterministic k-center init over the candidate pool
+            init_idx, _ = farthest_point_anchors(jnp.asarray(cand), k)
+            cur = np.asarray(cand)[np.asarray(init_idx)]      # [k, dim]
+            history = [float(np.asarray(
+                scorer(cost_table(cur[None], mu)))[0])]
+        for _ in range(rounds):
+            with trace.span("cluster.search.round"):
+                with trace.span("cluster.search.build"):
+                    # row 0: current set; row 1 + i*ncand + j: swap
+                    # center i -> cand j
+                    sets = np.broadcast_to(
+                        cur, (k * ncand, k, cur.shape[1])).copy()
+                    sets = sets.reshape(k, ncand, k, -1)
+                    for i in range(k):
+                        sets[i, :, i, :] = cand
+                    batch = np.concatenate(
+                        [cur[None], sets.reshape(k * ncand, k, -1)])
+                with trace.span("cluster.search.encode"):
+                    table = cost_table(batch, mu)
+                scores = np.asarray(scorer(table))
+                del table
+                best = int(np.argmin(scores[1:])) + 1
+                if scores[best] < scores[0] * (1.0 - tol):
+                    i, j = divmod(best - 1, ncand)
+                    cur = cur.copy()
+                    cur[i] = cand[j]
+                    history.append(float(scores[best]))
+                else:
+                    break
     return ClusterResult(centers=cur, est_cost=history[-1],
                          history=history, rounds=len(history) - 1)
 
