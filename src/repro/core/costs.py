@@ -37,6 +37,7 @@ boolean segment matrix.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import NamedTuple, Optional, Sequence, Union
 
 import jax
@@ -127,27 +128,57 @@ def cost_table(center_sets, mu: float = 1.0) -> CostTable:
     return encode_cost_queries([cost_query(c, mu) for c in sets])
 
 
+def swap_cost_table(cur, cand, mu) -> CostTable:
+    """Cost table of one swap round, built where ``cur`` and ``cand`` live:
+    row 0 is the current set ``cur`` [k, dim] and row 1 + i*n + j is ``cur``
+    with center i replaced by candidate j of ``cand`` [n, dim], every slot
+    valid, one cost-mode ``mu``. Bit for bit what ``cost_table`` encodes
+    from the same sets.
+
+    Only ``centers`` depends on the sets: a search builds the table once
+    and rebuilds its centers each round with ``swap_centers``."""
+    centers = swap_centers(cur, cand)
+    q, k = centers.shape[:2]
+    return CostTable(centers, *(jnp.asarray(x) for x in (
+        np.ones((q, k), bool), np.full((q,), mu, np.float32),
+        np.zeros((q,), np.float32), np.full((q,), MODE_COST, np.int32))))
+
+
+@jax.jit
+def swap_centers(cur, cand):
+    """The centers of ``swap_cost_table(cur, cand, mu)``: copies of ``cur``
+    and ``cand`` selected by slot."""
+    k, n = cur.shape[0], cand.shape[0]
+    swap = jnp.repeat(jnp.eye(k, dtype=bool), n, axis=0)   # row i*n + j: i
+    sets = jnp.where(swap[:, :, None], jnp.tile(cand, (k, 1))[:, None],
+                     cur[None])
+    return jnp.concatenate([cur[None], sets])
+
+
+_DTYPES = CostTable(np.float32, bool, np.float32, np.float32, np.int32)
+
+
 def pad_cost_table(table: CostTable, q_pad: int) -> CostTable:
     """Pad to ``q_pad`` rows with null queries (no valid centers -> estimate
-    exactly 0) so same-bucket batches share one compiled executable."""
+    exactly 0) so same-bucket batches share one compiled executable.
+
+    A table whose fields are all on the device is padded there (one
+    compiled program per shape and ``q_pad``); any other comes back as
+    numpy, padded on the host."""
     q = table.mu.shape[0]
+    if all(isinstance(x, jax.Array) for x in table):
+        return table if q >= q_pad else _pad_rows(table, q_pad)
+    host = CostTable(*(np.asarray(x, dt) for x, dt in zip(table, _DTYPES)))
     if q >= q_pad:
-        return table
-    pad = q_pad - q
-    return CostTable(
-        centers=np.concatenate(
-            [np.asarray(table.centers, np.float32),
-             np.zeros((pad,) + tuple(np.shape(table.centers)[1:]),
-                      np.float32)]),
-        cvalid=np.concatenate([np.asarray(table.cvalid, bool),
-                               np.zeros((pad, np.shape(table.cvalid)[1]),
-                                        bool)]),
-        mu=np.concatenate([np.asarray(table.mu, np.float32),
-                           np.zeros((pad,), np.float32)]),
-        param=np.concatenate([np.asarray(table.param, np.float32),
-                              np.zeros((pad,), np.float32)]),
-        mode=np.concatenate([np.asarray(table.mode, np.int32),
-                             np.zeros((pad,), np.int32)]))
+        return host
+    return CostTable(*(np.concatenate(
+        [x, np.zeros((q_pad - q,) + x.shape[1:], x.dtype)]) for x in host))
+
+
+@partial(jax.jit, static_argnums=1)
+def _pad_rows(table, q_pad):
+    return CostTable(*(jnp.pad(x, ((0, q_pad - x.shape[0]),)
+                               + ((0, 0),) * (x.ndim - 1)) for x in table))
 
 
 def sq_dists(centers, points) -> jnp.ndarray:

@@ -41,7 +41,8 @@ import numpy as np
 
 from repro.core.costs import (CostTable, ball_query, cost_table,
                               encode_cost_queries, estimate_service_costs,
-                              exact_service_costs, pad_cost_table)
+                              exact_service_costs, pad_cost_table,
+                              swap_centers, swap_cost_table)
 from repro.core.funcs import SUM
 from repro.core.metric_domains import (anchor_upper_weights,
                                        farthest_point_anchors)
@@ -272,22 +273,21 @@ class ClusterEngine:
         slab regardless of Q and Cmax (kernels.servicecost tiles Q inside
         the launch, so its VMEM footprint is bounded for any batch); Q pads
         to ``q_quantum`` with null rows so same-bucket batches share one
-        compiled executable.
+        compiled executable. A table whose fields are all device arrays
+        (``swap_cost_table``) is padded on the device and never crosses the
+        host; any other is encoded and padded on the host and copied over
+        by the launch.
 
         Under a profiler capture it records the spans ``cluster.score``
         (the call), ``.prep`` (encode and pad), ``.dispatch`` (the
         estimate's host-to-device hand-off and launch) and ``.wait`` (the
         read-back, which blocks on the kernel), and the counters
-        ``cluster.score.sets`` (Q) and ``.table_bytes`` (the padded
-        table's five fields)."""
+        ``cluster.score.sets`` (Q), ``.table_bytes`` (the padded table's
+        five fields, wherever they live) and ``.upload_bytes`` (those of
+        them copied from host memory; 0 for a device table)."""
         with trace.span("cluster.score"):
             with trace.span("cluster.score.prep"):
-                table = encode_cost_queries(queries)
-                table = CostTable(*(np.asarray(x) for x in table))
-                q = table.mu.shape[0]
-                qpad = max(self.q_quantum,
-                           -(-q // self.q_quantum) * self.q_quantum)
-                table = pad_cost_table(table, qpad)
+                table, q = self._padded(queries)
             with trace.span("cluster.score.dispatch"):
                 est = estimate_service_costs(
                     self._coords, self._sketch.probs, self._sketch.member,
@@ -297,7 +297,17 @@ class ClusterEngine:
         trace.count("cluster.score.sets", q)
         trace.count("cluster.score.table_bytes",
                     lambda: sum(x.nbytes for x in table))
+        trace.count("cluster.score.upload_bytes",
+                    lambda: sum(x.nbytes for x in table
+                                if not isinstance(x, jax.Array)))
         return out
+
+    def _padded(self, queries):
+        """(the encoded table padded to its ``q_quantum`` bucket, Q)."""
+        table = encode_cost_queries(queries)
+        q = table.mu.shape[0]
+        qpad = max(self.q_quantum, -(-q // self.q_quantum) * self.q_quantum)
+        return pad_cost_table(table, qpad), q
 
     def clustering_cost(self, centers, mu: Optional[float] = None) -> float:
         """Estimated Sum_x min_{c in centers} d(x,c)^mu for ONE set."""
@@ -361,10 +371,18 @@ def local_search(engine: ClusterEngine, k: int, mu: Optional[float] = None,
     pass :func:`exact_scorer` to run the identical search on ground-truth
     costs.
 
+    The round's table is built on the device (``swap_cost_table``) from
+    the candidates, uploaded once a search, and the current set, uploaded
+    once a round, so the engine scores it without a host copy; a round
+    rebuilds only its centers (``swap_centers``). A search of no rounds
+    compiles what a round runs (``swap_centers``, the engine's pad, and the
+    read of one set's rows, as a scorer reads its sets), so callers warm
+    a centre count's shapes with it.
+
     Under a profiler capture it records the spans ``cluster.search`` (the
     call), ``.seed`` (candidates, farthest-point init, first score),
-    ``.round`` (one swap round), ``.build`` (the round's swap sets) and
-    ``.encode`` (their cost table).
+    ``.round`` (one swap round), ``.build`` (the current set's upload) and
+    ``.encode`` (the device build of the round's cost table).
     """
     mu = engine.mu if mu is None else float(mu)
     if scorer is None:
@@ -374,25 +392,30 @@ def local_search(engine: ClusterEngine, k: int, mu: Optional[float] = None,
             cand = _candidate_pool(engine, n_cand)
             ncand = cand.shape[0]
             k = min(k, ncand)
+            cand_dev = jnp.asarray(cand)
             # deterministic k-center init over the candidate pool
-            init_idx, _ = farthest_point_anchors(jnp.asarray(cand), k)
-            cur = np.asarray(cand)[np.asarray(init_idx)]      # [k, dim]
+            init_idx, _ = farthest_point_anchors(cand_dev, k)
+            cur = cand[np.asarray(init_idx)]                  # [k, dim]
             history = [float(np.asarray(
                 scorer(cost_table(cur[None], mu)))[0])]
+            # the rounds share every field but the centers
+            table = swap_cost_table(jnp.asarray(cur), cand_dev, mu)
+            if not rounds:
+                # a search of no rounds is how callers warm a centre
+                # count: compile the rest of what a round runs too
+                engine._padded(table)
+                table.centers[0], table.cvalid[0]             # one set
+            fields = table._replace(centers=None)
+            del table
         for _ in range(rounds):
             with trace.span("cluster.search.round"):
                 with trace.span("cluster.search.build"):
+                    cur_dev = jnp.asarray(cur)
+                with trace.span("cluster.search.encode"):
                     # row 0: current set; row 1 + i*ncand + j: swap
                     # center i -> cand j
-                    sets = np.broadcast_to(
-                        cur, (k * ncand, k, cur.shape[1])).copy()
-                    sets = sets.reshape(k, ncand, k, -1)
-                    for i in range(k):
-                        sets[i, :, i, :] = cand
-                    batch = np.concatenate(
-                        [cur[None], sets.reshape(k * ncand, k, -1)])
-                with trace.span("cluster.search.encode"):
-                    table = cost_table(batch, mu)
+                    table = fields._replace(
+                        centers=swap_centers(cur_dev, cand_dev))
                 scores = np.asarray(scorer(table))
                 del table
                 best = int(np.argmin(scores[1:])) + 1

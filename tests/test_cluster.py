@@ -8,8 +8,9 @@ import jax.numpy as jnp
 import pytest
 
 import repro.core as C
-from repro.core.costs import (ball_query, cost_query, cost_table,
-                              encode_cost_queries, pad_cost_table)
+from repro.core.costs import (CostTable, ball_query, cost_query, cost_table,
+                              encode_cost_queries, pad_cost_table,
+                              swap_cost_table)
 from repro.kernels import ref as R
 from repro.kernels.servicecost import service_cost_slab
 from repro.launch.cluster import (ClusterEngine, exact_scorer, kcenter,
@@ -248,6 +249,105 @@ def test_cluster_engine_absorb_grows_count():
     eng.absorb(rng.normal(0, 1, (200, 2)).astype(np.float32))
     assert eng.total_count() > c1
     assert eng.epoch == 2
+
+
+# ------------------------------------------------ device-built swap tables
+def _host_swap_table(cur, cand, mu):
+    """A swap round's table as the host encodes it: row 0 the current set,
+    row 1 + i*n + j the set with center i swapped for candidate j."""
+    k, n = cur.shape[0], cand.shape[0]
+    sets = np.broadcast_to(cur, (k * n,) + cur.shape).copy()
+    sets = sets.reshape(k, n, k, -1)
+    for i in range(k):
+        sets[i, :, i, :] = cand
+    return cost_table(np.concatenate([cur[None], sets.reshape(k * n, k, -1)]),
+                      mu)
+
+
+@pytest.mark.parametrize("k,n,dim", [(1, 8, 3), (3, 8, 3), (4, 5, 13),
+                                     (2, 7, 68)])
+def test_swap_cost_table_matches_host_construction(k, n, dim):
+    """The device-built table's five fields equal the host encoding bit for
+    bit (k = 1, ragged candidate pools, dims off the sublane quantum)."""
+    rng = np.random.default_rng(k * 100 + n)
+    cur = rng.normal(0, 3, (k, dim)).astype(np.float32)
+    cand = rng.normal(0, 3, (n, dim)).astype(np.float32)
+    cand[0, 0] = -0.0                                # a sign bit survives
+    want = _host_swap_table(cur, cand, 2.0)
+    got = swap_cost_table(jnp.asarray(cur), jnp.asarray(cand), 2.0)
+    assert got.centers.shape == (1 + k * n, k, dim)
+    for name, w, g in zip(CostTable._fields, want, got):
+        assert isinstance(g, jax.Array), name
+        g = np.asarray(g)
+        assert (g.dtype, g.shape) == (w.dtype, w.shape), name
+        assert g.tobytes() == w.tobytes(), name
+
+
+@pytest.mark.parametrize("k,n_cand,slab", [(1, 8, 48), (3, 8, 48),
+                                           (3, 32, 12)])
+def test_local_search_device_tables_match_host_tables(k, n_cand, slab):
+    """The search scores its device-built tables as it scores the same
+    tables brought back to numpy: same centers, history and rounds (the
+    last case's sample holds fewer members than ``n_cand``)."""
+    eng = ClusterEngine.fit(_points(dim=5), k=slab, mu=2.0, seed=3)
+    host = []
+
+    def on_host(t):
+        host.append(t)
+        return eng.service_costs(CostTable(*(np.asarray(x) for x in t)))
+
+    a = local_search(eng, k, rounds=4, n_cand=n_cand, tol=-1.0)
+    b = local_search(eng, k, rounds=4, n_cand=n_cand, tol=-1.0,
+                     scorer=on_host)
+    assert isinstance(host[1].centers, jax.Array)
+    if slab < n_cand:
+        assert host[1].centers.shape[0] < 1 + k * n_cand
+    np.testing.assert_array_equal(a.centers, b.centers)
+    assert a.history == b.history and a.rounds == b.rounds == 4
+
+
+@pytest.mark.parametrize("uk", [True, False])
+def test_service_costs_device_table_matches_host_table(uk, tmp_path):
+    """A device table scores bit for bit as the same table in numpy, on the
+    kernel and the XLA path, with Q off the quantum; only the host table's
+    fields are counted as uploaded."""
+    from repro.telemetry import trace
+    eng = ClusterEngine.fit(_points(dim=5), k=48, mu=2.0, seed=3,
+                            q_quantum=16, use_kernels=uk)
+    rng = np.random.default_rng(7)
+    dev = swap_cost_table(
+        jnp.asarray(rng.normal(0, 3, (3, 5)).astype(np.float32)),
+        jnp.asarray(rng.normal(0, 3, (7, 5)).astype(np.float32)), 2.0)
+    host = CostTable(*(np.asarray(x) for x in dev))
+    assert host.mu.shape[0] % 16 != 0
+    outs, counters = [], []
+    trace.reset()
+    try:
+        for t in (dev, host):
+            with jax.profiler.trace(str(tmp_path)):
+                outs.append(eng.service_costs(t))
+            counters.append(trace.snapshot().counters)
+            trace.reset()
+    finally:
+        trace.reset()
+    assert outs[0].shape == (22,)
+    np.testing.assert_array_equal(outs[0], outs[1])
+    padded = sum(x.nbytes for x in pad_cost_table(host, 32))
+    assert counters[0]["cluster.score.upload_bytes"] == 0
+    assert counters[1]["cluster.score.upload_bytes"] == padded
+    assert (counters[0]["cluster.score.table_bytes"]
+            == counters[1]["cluster.score.table_bytes"] == padded)
+
+
+def test_pad_cost_table_device_rows_match_host_rows():
+    t = swap_cost_table(jnp.ones((2, 3), jnp.float32),
+                        jnp.zeros((4, 3), jnp.float32), 1.0)
+    dev = pad_cost_table(t, 16)
+    host = pad_cost_table(CostTable(*(np.asarray(x) for x in t)), 16)
+    for d, h in zip(dev, host):
+        assert isinstance(d, jax.Array) and isinstance(h, np.ndarray)
+        assert np.asarray(d).tobytes() == h.tobytes()
+    assert pad_cost_table(t, 8) is t
 
 
 # ------------------------------------------------ optimizer vs exact oracle

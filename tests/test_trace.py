@@ -83,9 +83,12 @@ def test_search_span_tree_and_counters(engine, tmp_path):
     qpad = [-(-x // 16) * 16 for x in q]
     row = [s[1] * s[2] * 4 + s[1] + 12 for s in shapes]  # f32, bool, 3 x 4 B
     assert q == [1, 25, 25] and qpad == [16, 32, 32]
+    # only the seed's one-set table is on the host: the rounds' tables are
+    # built on the device and never uploaded
     assert snap.counters == {
         "cluster.score.sets": sum(q),
-        "cluster.score.table_bytes": sum(p * r for p, r in zip(qpad, row))}
+        "cluster.score.table_bytes": sum(p * r for p, r in zip(qpad, row)),
+        "cluster.score.upload_bytes": qpad[0] * row[0]}
 
 
 def test_jax_traces_counts_a_new_q_bucket_once(engine, tmp_path):
@@ -98,6 +101,21 @@ def test_jax_traces_counts_a_new_q_bucket_once(engine, tmp_path):
         second = trace.snapshot().counters
     assert first["jax.traces"] == 1
     assert second["jax.traces"] == 1
+
+
+def test_a_search_warmed_as_the_benchmark_warms_it_traces_nothing(tmp_path):
+    """Set-up as the benchmark's: a search of no rounds, then one host
+    table of the round's shape; the rounds that follow (``swap_centers``,
+    device pad, kernel) trace nothing."""
+    eng = ClusterEngine.fit(_points(dim=6, seed=4), k=40, mu=2.0, seed=5,
+                            q_quantum=16)
+    local_search(eng, 4, rounds=0, n_cand=8)
+    eng.service_costs(cost_table(np.broadcast_to(_points(dim=6)[:4],
+                                                 (1 + 4 * 8, 4, 6)), 2.0))
+    with jax.profiler.trace(str(tmp_path)):
+        res = local_search(eng, 4, rounds=2, n_cand=8, tol=-1.0)
+    assert res.rounds == 2
+    assert trace.snapshot().counters.get("jax.traces", 0) == 0
 
 
 def test_spans_are_written_into_the_profilers_trace(engine, tmp_path):
