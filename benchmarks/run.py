@@ -132,6 +132,15 @@ def bench_thm_3_1_estimation_cv():
                 f"cv={cv:.3f};bound={bound:.3f};ok={cv <= bound}")
 
 
+def _kernel_select(objs, k: int):
+    """The jitted kernel chain (member, prob) for (kind, param) objectives,
+    each at budget k."""
+    spec = C.MultiSketchSpec(objectives=tuple(
+        (K.ops.statfn_of(kind, param), k) for kind, param in objs))
+    return jax.jit(lambda keys, w, act: C.multisketch_select(
+        spec, keys, w, act, use_kernels=True)[:2])
+
+
 def bench_sampling_throughput():
     """Production sort+scan vs fused kernels (keys/s)."""
     n, k = 65_536, 64
@@ -145,8 +154,9 @@ def bench_sampling_throughput():
             f"keys_per_s={n/us_prod*1e6:.3g};seed_recorded=3.18e5;"
             f"speedup_vs_seed={n/us_prod*1e6/3.18e5:.2f}x")
     objs = ((0, 0.0), (3, 2.0), (1, 0.0))
-    us_k = _timeit(lambda: K.ops.multi_objective_bottomk_kernel(
-        jnp.asarray(keys), jnp.asarray(w), jnp.asarray(act), objs, k)[0])
+    select = _kernel_select(objs, k)
+    us_k = _timeit(lambda: select(
+        jnp.asarray(keys), jnp.asarray(w), jnp.asarray(act))[0])
     _record("throughput_multiobj_kernel", us_k,
             f"keys_per_s={n/us_k*1e6:.3g};note=interpret_mode_cpu")
 
@@ -509,14 +519,16 @@ _SCALING_POOL = ((0, 0.0), (3, 2.0), (1, 0.0), (2, 5.0),
 def _per_objective_loop(keys, weights, active, objectives, k):
     """The seed's multi-objective path: |F| separate block-select launches
     plus a per-objective StatFn/prob pass — the flat-vs-linear baseline."""
-    from repro.core.bottomk import conditional_prob
+    from repro.core.bottomk import bottomk_members, conditional_prob
     n = keys.shape[0]
     seeds = K.fused_seeds(keys, weights, active, objectives)
     member = jnp.zeros((n,), bool)
     prob = jnp.zeros((n,), jnp.float32)
     for j, (kind, param) in enumerate(objectives):
-        vals, idx, tau = K.bottomk_select(seeds[j], k)
-        m = (seeds[j] <= vals[k - 1]) & jnp.isfinite(seeds[j])
+        vals, idx, _ = K.bottomk_select(seeds[j], k + 1)
+        m, tau, _ = bottomk_members(seeds[j][None], keys, (k,),
+                                    (vals[None], idx[None]))
+        m, tau = m[0], tau[0]
         fv = jnp.where(active,
                        K.ops.statfn_of(kind, param)(
                            jnp.asarray(weights, jnp.float32)), 0.0)
@@ -538,8 +550,8 @@ def bench_multiobj_scaling():
     base_fused = base_loop = None
     for nf in (1, 2, 4, 8):
         objs = _SCALING_POOL[:nf]
-        us_f = _timeit(lambda: K.ops.multi_objective_bottomk_kernel(
-            keys, w, act, objs, k)[0])
+        select = _kernel_select(objs, k)
+        us_f = _timeit(lambda: select(keys, w, act)[0])
         us_l = _timeit(lambda: _per_objective_loop(keys, w, act, objs, k)[0])
         if base_fused is None:
             base_fused, base_loop = us_f, us_l

@@ -311,8 +311,8 @@ def phase_a(args, state_root, failures):
                 ["segment_query"] if uk else [], failures)
     compile_s += program_kernels(
         "finalize_probs", MS._finalize_probs_jit,
-        (leaves.weights, leaves.seeds, leaves.member, leaves.valid,
-         leaves.taus), dict(spec=spec), [], failures)
+        (leaves.keys, leaves.weights, leaves.seeds, leaves.member,
+         leaves.valid, leaves.taus), dict(spec=spec), [], failures)
 
     # -- the stream ------------------------------------------------------
     pool = EnginePool(durability_dir=tempfile.mkdtemp(prefix="pool-",

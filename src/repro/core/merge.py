@@ -267,8 +267,9 @@ def build_sketch(keys, weights, active, k: int, capacity: int,
 def _compact(keys, weights, s: UniversalSample, k: int, capacity: int,
              seed: int) -> Sketch:
     keep = s.member | s.aux
-    # order: kept first (members before aux), then by weight desc
-    order = jnp.lexsort((-jnp.asarray(weights, jnp.float32), ~s.member, ~keep))
+    # order: kept first (members before aux), then by weight desc, then key
+    order = jnp.lexsort((jnp.asarray(keys, jnp.int32),
+                         -jnp.asarray(weights, jnp.float32), ~s.member, ~keep))
     n = order.shape[0]
     if n < capacity:  # pad so every sketch carries exactly `capacity` slots
         order = jnp.concatenate([order, jnp.zeros(capacity - n, order.dtype)])

@@ -12,10 +12,9 @@ from __future__ import annotations
 
 from typing import NamedTuple, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 
-from .bottomk import conditional_prob, f_seed
+from .bottomk import bottomk_members_any_order, conditional_prob, f_seed
 from .funcs import StatFn
 from .hashing import uniform01
 from .pps import pps_probabilities
@@ -54,9 +53,9 @@ def multi_bottomk_sample(keys, weights, active,
     """Multi-objective bottom-k sample S^(F) with aux keys Z (paper §3.2).
 
     All per-objective samples share the same u_x (coordination). For each
-    objective (f, k_f):
-      member_f(x): f-seed(x) among k_f smallest
-      tau_f = (k_f+1)-th smallest f-seed  (threshold key = the arg of tau_f)
+    objective (f, k_f) the members, tau_f and the threshold key (the
+    (k_f+1)-th entry) follow ``bottomk.bottomk_members``: the first k_f
+    by (f-seed, key). Keys may come in any order.
     Z collects, for each member x, the threshold key y_x of its most forgiving
     objective g_x — keys that are needed to recompute p_x^(F) from the sample
     but are not themselves members.
@@ -65,25 +64,16 @@ def multi_bottomk_sample(keys, weights, active,
     n = weights.shape[0]
     nf = len(objectives)
 
-    # Seeds and f-values for every objective under the SAME u_x, stacked
-    # [|F|, n]; thresholds for ALL objectives come from ONE batched
-    # top_k(max_k + 1) scan instead of 2 full-n scans per objective.
+    # seeds and f-values for every objective under the SAME u_x, [|F|, n]
     seeds_F = jnp.stack([f_seed(weights, active, f, u, scheme)
                          for f, _ in objectives])
     fv_F = jnp.stack([jnp.where(active, f(weights), 0.0)
                       for f, _ in objectives])
-    kks = [min(kf, n) for _, kf in objectives]
-    sorted_vals = -jax.lax.top_k(-seeds_F, min(max(kks) + 1, n))[0]
-    kth = jnp.stack([sorted_vals[j, kk - 1] for j, kk in enumerate(kks)])
-    taus = jnp.stack([sorted_vals[j, kk] if n > kk else jnp.float32(jnp.inf)
-                      for j, kk in enumerate(kks)])
-
-    members_F = ((seeds_F < kth[:, None])
-                 | ((seeds_F == kth[:, None]) & jnp.isfinite(seeds_F)))
+    members_F, taus, thr = bottomk_members_any_order(
+        seeds_F, jnp.asarray(keys, jnp.int32), tuple(k for _, k in objectives))
     probs = jnp.where(members_F,
                       conditional_prob(fv_F, taus[:, None], scheme), 0.0)
-    # threshold key of objective f: the key whose seed == tau_f
-    thr_key_onehots = jnp.isfinite(taus)[:, None] & (seeds_F == taus[:, None])
+    thr_key_onehots = jnp.arange(n)[None, :] == thr[:, None]
 
     member = members_F.any(axis=0)
     p_F = probs.max(axis=0)

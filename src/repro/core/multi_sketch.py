@@ -53,7 +53,8 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .bottomk import conditional_prob, f_seed
+from .bottomk import (bottomk_members, bottomk_members_any_order,
+                      conditional_prob, f_seed)
 from .funcs import StatFn
 from .hashing import uniform01
 
@@ -159,22 +160,24 @@ def multisketch_select(spec: MultiSketchSpec, keys, weights, active,
     """Multi-objective bottom-k selection with the MERGEABLE aux set.
 
     Returns (member [n], prob [n] = p^(F), aux [n], seeds [nf, n],
-    taus [nf]). Differs from core.multi_objective.multi_bottomk_sample only
-    in Z: aux holds the threshold key of EVERY objective (merge-sufficient
-    superset) instead of the estimation-minimal pruned set; member and prob
-    are identical. ``seed`` (runtime override, may be traced) defaults to
-    the static spec.seed.
+    taus [nf]). Membership and tau are ``bottomk.bottomk_members``: the
+    f-members are the k_f active keys first in (f-seed, key) order, and
+    aux holds the (k_f+1)-th, the threshold key, of EVERY objective (a
+    merge-sufficient superset of the estimation-minimal Z of
+    core.multi_objective.multi_bottomk_sample; member and prob are the
+    same). ``keys`` must come in ascending order, as ``_rebuild`` sorts
+    them: the selection ranks equal seeds by position. ``seed`` (runtime
+    override, may be traced) defaults to the static spec.seed.
     """
     keys = jnp.asarray(keys, jnp.int32)
     w = jnp.asarray(weights, jnp.float32)
     act = jnp.asarray(active, bool)
     n = keys.shape[0]
-    nf = spec.nf
-    kks = [min(kf, n) for _, kf in spec.objectives]
-    kmax = max(kks)
+    ks = tuple(kf for _, kf in spec.objectives)
     seed = spec.seed if seed is None else seed
 
     enc = spec.kernel_objectives()
+    cand = None
     # the seeds kernel bakes the seed in as a compile-time constant; traced
     # seeds (e.g. per-step reseeding inside a jitted exchange) take the
     # XLA path, which accepts them at runtime.
@@ -183,44 +186,30 @@ def multisketch_select(spec: MultiSketchSpec, keys, weights, active,
         from repro.kernels.seeds import fused_seeds_fvals
         seeds, fvals = fused_seeds_fvals(keys, w, act, enc, spec.scheme,
                                          int(seed))
-        vals, idx, _ = batched_bottomk_select(seeds, kmax + 1)
+        cand = batched_bottomk_select(seeds, max(ks) + 1)[:2]
     else:
         u = uniform01(keys, seed)
         seeds = jnp.stack([f_seed(w, act, f, u, spec.scheme)
                            for f, _ in spec.objectives])
         fvals = jnp.stack([jnp.where(act, f(w), 0.0)
                            for f, _ in spec.objectives])
-        m = min(kmax + 2, n)
-        neg, idx = jax.lax.top_k(-seeds, m)     # ONE scan for all objectives
-        vals, idx = -neg, idx.astype(jnp.int32)
 
-    # per-objective k-th / (k+1)-th smallest + the threshold key's position
-    if vals.shape[1] < kmax + 1:                # n <= kmax: no (k+1)-th seed
-        pad = kmax + 1 - vals.shape[1]
-        vals = jnp.pad(vals, ((0, 0), (0, pad)), constant_values=jnp.inf)
-        idx = jnp.pad(idx, ((0, 0), (0, pad)), constant_values=-1)
-    rows = jnp.arange(nf)
-    kth = vals[rows, jnp.asarray(kks) - 1]                       # [nf]
-    taus = vals[rows, jnp.asarray(kks)]                          # [nf]
-    thr_idx = idx[rows, jnp.asarray(kks)]                        # [nf]
-
-    member_f = (seeds <= kth[:, None]) & jnp.isfinite(seeds)
+    member_f, taus, thr = bottomk_members(seeds, keys, ks, cand)
     p_f = jnp.where(member_f,
                     conditional_prob(fvals, taus[:, None], spec.scheme), 0.0)
     member = member_f.any(axis=0)
     prob = jnp.where(member, p_f.max(axis=0), 0.0)
-
-    # Z: the (k_f+1)-th smallest-seed key of every objective (if it exists)
-    safe = jnp.where(jnp.isfinite(taus) & (thr_idx >= 0), thr_idx, n)
-    aux = jnp.zeros((n,), bool).at[safe].set(True, mode="drop") & ~member
+    aux = (jnp.zeros((n,), bool).at[jnp.where(thr >= 0, thr, n)]
+           .set(True, mode="drop") & ~member)
     return member, prob, aux, seeds, taus
 
 
 def _compact(spec: MultiSketchSpec, keys, weights, member, prob, aux, seeds,
              taus, use_kernels: bool) -> MultiSketch:
-    """Compact S^(F) ∪ Z into the fixed-capacity slab (members by weight
-    desc first, then aux). ``keys`` must be key-sorted if duplicates are
-    possible; here they are pre-deduped so order is free."""
+    """Compact S^(F) ∪ Z into the fixed-capacity slab: members by weight
+    desc, then aux by weight desc, equal priorities by key. ``keys`` must
+    come in ascending order and be deduped: both takes rank equal
+    priorities by position."""
     c = spec.cap
     keep = member | aux
     if use_kernels:
@@ -250,9 +239,10 @@ def _compact(spec: MultiSketchSpec, keys, weights, member, prob, aux, seeds,
 
 
 def _rebuild(spec: MultiSketchSpec, keys, weights, valid,
-             use_kernels: bool) -> MultiSketch:
-    """Dedup (keep max weight — the paper's w_x for merged data sets),
-    re-select, compact. The shared exact-merge core of absorb/merge."""
+             use_kernels: bool, seed=None) -> MultiSketch:
+    """Sort by key, dedup (keep max weight — the paper's w_x for merged
+    data sets), select, compact: the one core of build, absorb and merge,
+    so that each sees its entries in the same order."""
     keys = jnp.asarray(keys, jnp.int32)
     w = jnp.asarray(weights, jnp.float32)
     # sort (key asc, VALID first, weight desc): each key's first occurrence
@@ -277,7 +267,7 @@ def _rebuild(spec: MultiSketchSpec, keys, weights, valid,
     dup = jnp.concatenate([jnp.zeros((1,), bool), sk[1:] == sk[:-1]])
     act = sv & ~dup & (sk >= 0)
     member, prob, aux, seeds, taus = multisketch_select(
-        spec, sk, sw, act, use_kernels=use_kernels)
+        spec, sk, sw, act, use_kernels=use_kernels, seed=seed)
     return _compact(spec, sk, sw, member, prob, aux, seeds, taus,
                     use_kernels)
 
@@ -287,7 +277,7 @@ def _rebuild(spec: MultiSketchSpec, keys, weights, valid,
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("spec",))
-def _finalize_probs_jit(weights, seeds, member, valid, taus, *, spec):
+def _finalize_probs_jit(keys, weights, seeds, member, valid, taus, *, spec):
     """Recompute p^(F) from the compacted slab in ONE fixed-shape program.
 
     The retained multiset (keys/weights/seeds/member/taus) of any merge
@@ -300,15 +290,17 @@ def _finalize_probs_jit(weights, seeds, member, valid, taus, *, spec):
     keyed only by spec: identical slabs get identical prob bits no
     matter which fold produced them.
 
-    Per-objective membership is recovered as ``seed < tau`` (strict):
-    no seed lies strictly between the k-th smallest (the member bound)
-    and tau, the (k+1)-th, so strict-< reproduces the original
-    ``seed <= kth`` test exactly (modulo measure-zero seed ties at the
-    boundary, impossible for distinct keys under a continuous hash).
+    Per-objective membership is ``bottomk.bottomk_members`` over the
+    slab's valid entries. Every f-member and every threshold key is
+    retained, so the rule gives the selection's members and taus; the
+    slab holds its slots in retention order, so the rule sorts them by
+    key first.
     """
     fvals = jnp.stack([jnp.where(valid, f(weights), 0.0)
                        for f, _ in spec.objectives])
-    member_f = (seeds < taus[:, None]) & member[None, :]
+    member_f, _, _ = bottomk_members_any_order(
+        jnp.where(valid[None, :], seeds, _INF), keys,
+        tuple(k for _, k in spec.objectives))
     p_f = jnp.where(member_f,
                     conditional_prob(fvals, taus[:, None], spec.scheme), 0.0)
     return jnp.where(member, p_f.max(axis=0), 0.0)
@@ -320,34 +312,22 @@ def multisketch_finalize(sk: MultiSketch, *,
     every host-level producer in this module applies it on return, so
     slabs with equal retained state compare bit-equal in all 8 fields."""
     return sk._replace(probs=_finalize_probs_jit(
-        sk.weights, sk.seeds, sk.member, sk.valid, sk.taus, spec=spec))
+        sk.keys, sk.weights, sk.seeds, sk.member, sk.valid, sk.taus,
+        spec=spec))
 
 
 # ---------------------------------------------------------------------------
 # public entry points
 # ---------------------------------------------------------------------------
 
-def _build_body(keys, weights, active, spec, use_kernels, seed=None):
-    n = keys.shape[0]
-    npad = max(n, spec.kmax + 2)  # selection needs a (kmax+1)-th candidate
-    if npad > n:
-        keys = jnp.pad(keys, (0, npad - n), constant_values=-1)
-        weights = jnp.pad(weights, (0, npad - n))
-        active = jnp.pad(active, (0, npad - n))
-    member, prob, aux, seeds, taus = multisketch_select(
-        spec, keys, weights, active, use_kernels=use_kernels, seed=seed)
-    return _compact(spec, keys, weights, member, prob, aux, seeds, taus,
-                    use_kernels)
-
-
 @partial(jax.jit, static_argnames=("spec", "use_kernels"))
 def _build_jit(keys, weights, active, *, spec, use_kernels):
-    return _build_body(keys, weights, active, spec, use_kernels)
+    return _rebuild(spec, keys, weights, active, use_kernels)
 
 
 @partial(jax.jit, static_argnames=("spec", "use_kernels"))
 def _build_seeded_jit(keys, weights, active, seed, *, spec, use_kernels):
-    return _build_body(keys, weights, active, spec, use_kernels, seed=seed)
+    return _rebuild(spec, keys, weights, active, use_kernels, seed=seed)
 
 
 def multisketch_build(spec: MultiSketchSpec, keys, weights, active=None,
@@ -355,9 +335,11 @@ def multisketch_build(spec: MultiSketchSpec, keys, weights, active=None,
                       seed=None) -> MultiSketch:
     """One-shot S^(F) ∪ Z over a batch, compacted to the wire format.
 
-    Assumes distinct keys (as the paper's data model does); duplicate keys
-    in ONE batch are sampled as distinct observations — route repeated keys
-    through ``absorb``/``merge``, which dedup by max weight.
+    The same core as ``absorb`` and ``merge`` (``_rebuild``): the batch is
+    sorted by key, so the build equals a fold of the same keys into an
+    empty sketch, bit for bit. A key repeated in one batch is one key, of
+    its largest weight (the paper's w_x for merged data sets), as it is
+    across batches.
 
     ``seed``: optional RUNTIME hash-seed override (a traced int32 is fine)
     — many-seed callers (replication studies, the metric-domain sampler)

@@ -3,9 +3,12 @@
 Compacting S^(F) ∪ Z into ``capacity`` slots is a selection problem in
 disguise: assign every entry a retention PRIORITY (members first, then aux,
 each ordered by weight descending; dropped/duplicate/invalid entries +inf)
-and take the ``capacity`` smallest priorities. That reuses the PR 1 batched
-block-select kernel for the take, so the only new device code is the fused
-priority pass implemented here:
+and take the ``capacity`` smallest priorities. The take ranks equal
+priorities by position, and the inputs come in ascending key order, so the
+slot order is members by weight desc, then aux by weight desc, equal
+priorities by key: the order of ``core.multi_sketch._compact``'s XLA take.
+That reuses the batched block-select kernel for the take, so the only new
+device code is the fused priority pass implemented here:
 
   one VMEM-resident sweep computes, per entry,
     dup    — key equals the previous key (inputs are key-sorted with
@@ -59,7 +62,8 @@ def retention_priority(sorted_keys, weights, member, keep, interpret=None):
     the first, max-weight occurrence) and negative keys (empty slots) get
     priority +inf, as do entries with ``keep`` False. Returns pri [n] f32
     whose ascending order is: members by weight desc, then aux by weight
-    desc, then dropped.
+    desc, then dropped; equal priorities are left to the take, which ranks
+    them by position, i.e. by key.
     """
     interpret = resolve_interpret(interpret)
     n = sorted_keys.shape[0]
@@ -96,8 +100,9 @@ def compact_take(sorted_keys, weights, member, keep, capacity: int,
 
     Returns (take [capacity] int32, taken_valid [capacity] bool): positions
     of the ``capacity`` highest-retention entries (members by weight desc,
-    then aux), -1 / False on slots past the retained count. Exact via the
-    two-level block-select (the capacity smallest priorities).
+    then aux, equal weights by key since the inputs are key-sorted), -1 /
+    False on slots past the retained count. Exact via the two-level
+    block-select (the capacity smallest priorities, ties by position).
     """
     pri = retention_priority(sorted_keys, weights, member, keep,
                              interpret=interpret)

@@ -3,17 +3,10 @@ paper's sampling operations. ``interpret=None`` auto-detects the backend
 (interpret mode on CPU, compiled Mosaic on TPU); pass an explicit bool to
 override.
 
-The multi-objective path is a single-launch batched chain (paper §3.3:
-one summary for Omega(|F| n) work):
-
-  fused_seeds_fvals   ONE launch   -> seeds [F, n], fvals [F, n]
-  batched blockselect ONE launch   -> candidates [F, nb*(k+1)]
-  batched top_k merge ONE scan     -> kth/tau per objective
-  membership + conditional prob + max over F: vectorized [F, n] jnp ops
-
-No Python loop over objectives anywhere — launch count and scan count are
-flat in |F|; only the O(|F| n) bandwidth term remains, which is the
-paper's lower bound.
+The multi-objective bottom-k chain (fused seeds, batched block select,
+then the membership rule) is ``core.multi_sketch.multisketch_select``
+with ``use_kernels=True``; ``statfn_of`` maps its seeds kernel's
+(kind, param) objective encoding back to a StatFn.
 """
 from __future__ import annotations
 
@@ -22,12 +15,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.bottomk import conditional_prob
 from repro.core.funcs import StatFn
 from repro.core.hashing import rank_of, uniform01
-from .blockselect import batched_bottomk_select
 from .rankcount import rank_counts
-from .seeds import fused_seeds_fvals
 
 # objective encoding for the seeds kernel
 SUM, COUNT, THRESH, CAP, MOMENT = 0, 1, 2, 3, 4
@@ -38,29 +28,6 @@ _KIND_NAMES = {0: "sum", 1: "count", 2: "thresh", 3: "cap", 4: "moment"}
 def statfn_of(kind: int, param: float) -> StatFn:
     """The core StatFn equivalent of a (kind, param) kernel objective."""
     return StatFn(_KIND_NAMES[kind], float(param))
-
-
-@partial(jax.jit, static_argnames=("objectives", "k", "scheme", "seed",
-                                   "interpret"))
-def multi_objective_bottomk_kernel(keys, weights, active, objectives,
-                                   k: int, scheme="ppswor", seed=0,
-                                   interpret=None):
-    """Multi-objective bottom-k sample S^(F) via the fused batched kernels.
-
-    Returns (member [n] bool, prob [n] float32) — same semantics as
-    core.multi_objective.multi_bottomk_sample (member/prob only), with a
-    launch count independent of |F|.
-    """
-    n = keys.shape[0]
-    kk = min(k, n)
-    seeds, fvals = fused_seeds_fvals(keys, weights, active, objectives,
-                                     scheme, seed, interpret=interpret)
-    vals, _idx, tau = batched_bottomk_select(seeds, kk, interpret=interpret)
-    kth = vals[:, kk - 1]                                  # [F]
-    member_f = (seeds <= kth[:, None]) & jnp.isfinite(seeds)
-    p_f = jnp.where(member_f,
-                    conditional_prob(fvals, tau[:, None], scheme), 0.0)
-    return member_f.any(axis=0), p_f.max(axis=0)
 
 
 @partial(jax.jit, static_argnames=("k", "scheme", "seed", "interpret"))

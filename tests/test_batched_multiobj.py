@@ -1,7 +1,8 @@
 """Single-launch batched multi-objective bottom-k pipeline.
 
-Agreement of the fused kernel chain (seeds -> batched block-select ->
-batched merge -> vectorized estimate) with the core reference path on
+Agreement of the fused kernel chain (``multisketch_select`` with
+use_kernels: seeds -> batched block-select -> batched merge -> the
+membership rule) with the core reference path on
 shared u_x, across schemes, ragged n, and |F|; plus a launch-count
 regression: the number of pallas_call launches must NOT grow with |F|.
 """
@@ -13,7 +14,19 @@ import pytest
 import repro.core as C
 import repro.kernels as K
 from repro.kernels import ref as R
-from repro.kernels.ops import multi_objective_bottomk_kernel, statfn_of
+from repro.kernels.ops import statfn_of
+
+
+def _kernel_chain(keys, weights, active, objectives, k,
+                                   scheme="ppswor", seed=0):
+    """(member, prob) of the kernel chain for (kind, param) objectives."""
+    spec = C.MultiSketchSpec(
+        objectives=tuple((statfn_of(kind, prm), k)
+                         for kind, prm in objectives),
+        scheme=scheme, seed=seed)
+    return C.multisketch_select(spec, keys, weights, active,
+                                use_kernels=True)[:2]
+
 
 # (kind, param) pools — every family, several params
 _OBJ_POOL = ((0, 0.0), (1, 0.0), (2, 5.0), (3, 2.0), (4, 1.5),
@@ -39,7 +52,7 @@ def test_batched_kernel_matches_core(rng, scheme, n, nf):
     keys, w, act = _data(rng, n)
     k = 16
     objs = _objectives(nf)
-    m_k, p_k = multi_objective_bottomk_kernel(
+    m_k, p_k = _kernel_chain(
         jnp.asarray(keys), jnp.asarray(w), jnp.asarray(act), objs, k,
         scheme=scheme, seed=3)
     core = C.multi_bottomk_sample(
@@ -52,7 +65,7 @@ def test_batched_kernel_matches_core(rng, scheme, n, nf):
 def test_batched_kernel_matches_core_large_ragged(rng):
     keys, w, act = _data(rng, 3000)
     objs = _objectives(3)
-    m_k, p_k = multi_objective_bottomk_kernel(
+    m_k, p_k = _kernel_chain(
         jnp.asarray(keys), jnp.asarray(w), jnp.asarray(act), objs, 33)
     core = C.multi_bottomk_sample(
         keys, w, act, [(statfn_of(kind, prm), 33) for kind, prm in objs])
@@ -63,7 +76,7 @@ def test_batched_kernel_matches_core_large_ragged(rng):
 def test_k_not_smaller_than_n(rng):
     """k >= n: every active key is a member with p = 1 (tau = +inf)."""
     keys, w, act = _data(rng, 600)
-    m_k, p_k = multi_objective_bottomk_kernel(
+    m_k, p_k = _kernel_chain(
         jnp.asarray(keys), jnp.asarray(w), jnp.asarray(act),
         _objectives(2), 600)
     assert bool(jnp.all(m_k == jnp.asarray(act)))
@@ -145,8 +158,7 @@ def test_fused_path_launch_count_flat_in_F(nf):
     act = jnp.ones((n,), bool)
     objs = _objectives(nf)
     jx = jax.make_jaxpr(
-        lambda ke, we, ac: multi_objective_bottomk_kernel(ke, we, ac, objs,
-                                                          k))(keys, w, act)
+        lambda ke, we, ac: _kernel_chain(ke, we, ac, objs, k))(keys, w, act)
     assert _count_pallas_calls(jx.jaxpr) == 2
 
 
@@ -157,8 +169,7 @@ def test_unknown_scheme_rejected():
     w = jnp.ones((64,), jnp.float32)
     act = jnp.ones((64,), bool)
     with pytest.raises(ValueError, match="scheme"):
-        multi_objective_bottomk_kernel(keys, w, act, ((0, 0.0),), 8,
-                                       scheme="bogus")
+        _kernel_chain(keys, w, act, ((0, 0.0),), 8, scheme="bogus")
 
 
 # ------------------------------------------------------------- satellites

@@ -65,8 +65,12 @@ def test_kernel_composition_matches_core_multi_objective(rng):
     w = rng.lognormal(0, 1.5, n).astype(np.float32)
     act = rng.random(n) > 0.05
     objs = ((0, 0.0), (3, 2.0), (1, 0.0))
-    m_k, p_k = K.ops.multi_objective_bottomk_kernel(
-        jnp.asarray(keys), jnp.asarray(w), jnp.asarray(act), objs, k)
+    spec = C.MultiSketchSpec(
+        objectives=tuple((K.ops.statfn_of(kind, prm), k)
+                         for kind, prm in objs))
+    m_k, p_k = C.multisketch_select(
+        spec, jnp.asarray(keys), jnp.asarray(w), jnp.asarray(act),
+        use_kernels=True)[:2]
     core = C.multi_bottomk_sample(keys, w, act,
                                   [(C.SUM, k), (C.cap(2.0), k), (C.COUNT, k)],
                                   seed=0)

@@ -141,14 +141,19 @@ def _draw_to_inputs(key_seed, ws):
 # ------------------------------------------------- deterministic sweep
 @pytest.mark.parametrize("check", _CHECKS,
                          ids=lambda c: c.__name__.replace("check_", ""))
-@pytest.mark.parametrize("scheme,nf,slack,seed", [
-    ("ppswor", 3, 0, 0), ("priority", 3, 0, 7),
-    ("ppswor", 5, 9, 3), ("priority", 1, 4, 1)])
-def test_merge_properties_fixed_draws(check, scheme, nf, slack, seed):
+@pytest.mark.parametrize("scheme,nf,slack,seed,ws", [
+    pytest.param("ppswor", 3, 0, 0, None, id="ppswor-3-0-0"),
+    pytest.param("priority", 3, 0, 7, None, id="priority-3-0-7"),
+    pytest.param("ppswor", 5, 9, 3, None, id="ppswor-5-9-3"),
+    pytest.param("priority", 1, 4, 1, None, id="priority-1-4-1"),
+    # equal weights: slot order is decided by key alone
+    pytest.param("ppswor", 1, 0, 0, [1.0] * 6, id="ppswor-1-0-0-equal-w")])
+def test_merge_properties_fixed_draws(check, scheme, nf, slack, seed, ws):
     spec = _make_spec(scheme, nf, slack, seed)
-    rng = np.random.default_rng(100 + seed)
-    n = int(rng.integers(12, 90))
-    ws = rng.lognormal(0, 1.4, n).astype(np.float32)
+    if ws is None:
+        rng = np.random.default_rng(100 + seed)
+        n = int(rng.integers(12, 90))
+        ws = rng.lognormal(0, 1.4, n).astype(np.float32)
     keys, ws = _draw_to_inputs(seed, ws)
     check(spec, keys, ws)
 

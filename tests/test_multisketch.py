@@ -230,6 +230,102 @@ def test_absorb_is_jit_cached_and_donated():
     assert _absorb_jit._cache_size() == misses0 + 1
 
 
+@pytest.mark.parametrize("use_kernels", [False, True])
+def test_build_dedups_repeated_key_by_max_weight(use_kernels):
+    """A key repeated in one build batch is one key of its largest weight,
+    and the build equals folding the batch into an empty sketch."""
+    spec = C.MultiSketchSpec(objectives=((C.SUM, 2), (C.COUNT, 2)), seed=3)
+    keys = np.array([7, 4, 7, 9, 4, 12], np.int32)
+    w = np.array([3.0, 1.0, 5.0, 2.0, 0.5, 4.0], np.float32)
+    sk = C.multisketch_build(spec, keys, w, use_kernels=use_kernels)
+    valid = np.asarray(sk.valid)
+    got = dict(zip(np.asarray(sk.keys)[valid].tolist(),
+                   np.asarray(sk.weights)[valid].tolist()))
+    assert got == {7: 5.0, 4: 1.0, 9: 2.0, 12: 4.0}
+    folded = C.multisketch_absorb(C.multisketch_empty(spec), keys, w,
+                                  spec=spec, use_kernels=use_kernels)
+    for name, x, y in zip(C.MultiSketch._fields, sk, folded):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=name)
+
+
+def _tie_keys():
+    """215 keys whose COUNT seeds tie at the 16th and 17th places: the
+    first two ids of [0, 2^20) with equal 24-bit u under hash seed 7, the
+    15 ids just below them in u order and the 198 just above."""
+    ids = np.arange(1 << 20)
+    u24 = np.asarray(C.hash_u32(ids, 7)) >> 8
+    order = np.lexsort((ids, u24))
+    i = int(np.flatnonzero(np.diff(u24[order]) == 0)[0])
+    keys = order[i - 15:i + 2 + 198].astype(np.int32)
+    return np.random.default_rng(0).permutation(keys)
+
+
+def _tie_samples(case, spec, keys, w):
+    """(member keys, their p, taus, slab or None) of one sampler."""
+    act = np.ones(len(keys), bool)
+    if case in ("bottomk_sample", "multi_bottomk_sample"):
+        s = (C.bottomk_sample(keys, w, act, C.COUNT, 16, seed=7)
+             if case == "bottomk_sample" else
+             C.multi_bottomk_sample(keys, w, act, ((C.COUNT, 16),), seed=7))
+        m = np.asarray(s.member)
+        taus = s.tau if case == "bottomk_sample" else s.taus
+        return keys[m], np.asarray(s.prob)[m], np.atleast_1d(taus), None
+    if case.startswith("build"):
+        sk = C.multisketch_build(spec, keys, w,
+                                 use_kernels=case == "build_kernels")
+    elif case == "absorb":
+        sk = C.multisketch_empty(spec)
+        for part in np.array_split(np.arange(len(keys)), 3):
+            sk = C.multisketch_absorb(sk, keys[part], w[part], spec=spec)
+    else:
+        a, b = np.array_split(np.arange(len(keys)), 2)
+        sk = C.multisketch_merge(spec,
+                                 C.multisketch_build(spec, keys[a], w[a]),
+                                 C.multisketch_build(spec, keys[b], w[b]))
+    m = np.asarray(sk.member)
+    return np.asarray(sk.keys)[m], np.asarray(sk.probs)[m], sk.taus, sk
+
+
+@pytest.mark.parametrize("case", ["build_xla", "build_kernels", "absorb",
+                                  "merge", "bottomk_sample",
+                                  "multi_bottomk_sample"])
+def test_seed_tie_at_kth_place(case):
+    """Two keys with equal seeds at the k-th and (k+1)-th places: every
+    sampler keeps exactly k members, all with p > 0, the first k by
+    (seed, key), and estimates COUNT as that reference sample does."""
+    keys = _tie_keys()
+    w = np.ones(len(keys), np.float32)
+    spec = C.MultiSketchSpec(objectives=((C.COUNT, 16),), seed=7)
+    seeds = np.asarray(C.f_seed(w, np.ones(len(keys), bool), C.COUNT,
+                                C.uniform01(keys, 7), "ppswor"))
+    order = np.lexsort((keys, seeds))
+    assert seeds[order[15]] == seeds[order[16]]      # the tie is at k
+    want_keys, want_tau = np.sort(keys[order[:16]]), seeds[order[16]]
+    want_est = 16 / -np.expm1(-np.float64(want_tau))
+
+    got_keys, got_p, taus, sk = _tie_samples(case, spec, keys, w)
+    assert len(got_keys) == 16
+    assert np.all(got_p > 0)
+    np.testing.assert_array_equal(np.sort(got_keys), want_keys)
+    assert np.asarray(taus).tolist() == [want_tau]
+    est = float(np.sum(1.0 / got_p.astype(np.float64)))
+    assert np.isfinite(est) and abs(est / want_est - 1) < 1e-6
+    if sk is not None:
+        assert abs(float(C.multisketch_estimate(sk, C.COUNT)) / want_est
+                   - 1) < 1e-6
+        base = C.multisketch_build(spec, keys, w, use_kernels=False)
+        for name, x, y in zip(C.MultiSketch._fields, sk, base):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                          err_msg=name)
+        # the tau the finalizer's rule recomputes from the slab
+        from repro.core.bottomk import bottomk_members_any_order
+        _, slab_taus, _ = bottomk_members_any_order(
+            jnp.where(sk.valid[None, :], sk.seeds, jnp.inf), sk.keys, (16,))
+        np.testing.assert_array_equal(np.asarray(slab_taus),
+                                      np.asarray(sk.taus))
+
+
 _SHARDED_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 import repro.core as C
-from repro.core.multi_sketch import _build_body
+from repro.core.multi_sketch import _rebuild
 
 N, K, TRIALS = 1200, 32, 200
 # the empirical CV of T trials estimates the true CV with relative
@@ -45,7 +45,7 @@ def _trial_estimates(spec, keys, w, act):
     one HT pass per objective over the stacked slabs."""
     jk, jw, ja = jnp.asarray(keys), jnp.asarray(w), jnp.asarray(act)
     build = jax.jit(jax.vmap(
-        lambda s: _build_body(jk, jw, ja, spec, False, seed=s)))
+        lambda s: _rebuild(spec, jk, jw, ja, False, seed=s)))
     sks = build(jnp.arange(TRIALS, dtype=jnp.int32))
     segm = sks.keys % 3 == 0                      # the queried segment H
     out = []
