@@ -19,7 +19,8 @@ wire format is a pytree of arrays, not a static row encoding:
   mode    int32   [Q]             MODE_COST | MODE_BALL
 
 A row whose ``cvalid`` is all-False estimates exactly 0 in both modes —
-the padding element for Q-bucket quantization (``pad_cost_table``).
+the padding element for Q-bucket quantization: ``pad_cost_table`` adds it
+on the host, ``estimate_bucketed`` inside the launch (a device table).
 
 ``service_cost_values`` is the vectorized oracle shared by the XLA
 estimate path and the kernel tests; the fused Pallas kernel
@@ -159,26 +160,16 @@ _DTYPES = CostTable(np.float32, bool, np.float32, np.float32, np.int32)
 
 
 def pad_cost_table(table: CostTable, q_pad: int) -> CostTable:
-    """Pad to ``q_pad`` rows with null queries (no valid centers -> estimate
-    exactly 0) so same-bucket batches share one compiled executable.
-
-    A table whose fields are all on the device is padded there (one
-    compiled program per shape and ``q_pad``); any other comes back as
-    numpy, padded on the host."""
+    """-> the table as numpy, padded on the host to ``q_pad`` rows with null
+    queries (no valid centers -> estimate exactly 0) so same-bucket batches
+    share one compiled executable. A table already on the device is padded
+    inside the launch instead (``estimate_bucketed``)."""
     q = table.mu.shape[0]
-    if all(isinstance(x, jax.Array) for x in table):
-        return table if q >= q_pad else _pad_rows(table, q_pad)
     host = CostTable(*(np.asarray(x, dt) for x, dt in zip(table, _DTYPES)))
     if q >= q_pad:
         return host
     return CostTable(*(np.concatenate(
         [x, np.zeros((q_pad - q,) + x.shape[1:], x.dtype)]) for x in host))
-
-
-@partial(jax.jit, static_argnums=1)
-def _pad_rows(table, q_pad):
-    return CostTable(*(jnp.pad(x, ((0, q_pad - x.shape[0]),)
-                               + ((0, 0),) * (x.ndim - 1)) for x in table))
 
 
 def sq_dists(centers, points) -> jnp.ndarray:
@@ -248,6 +239,30 @@ def estimate_service_costs(points, probs, member, queries: CostQueries,
         CostTable(*(jnp.asarray(x) for x in table)),
         point_weights if point_weights is None
         else jnp.asarray(point_weights, jnp.float32))
+
+
+@partial(jax.jit, static_argnums=(4, 5))
+def estimate_bucketed(points, probs, member, table: CostTable, q_pad: int,
+                      use_kernels: Optional[bool] = None) -> jnp.ndarray:
+    """``estimate_service_costs`` of ``table``'s Q rows as if padded to
+    ``q_pad`` with null rows, in ONE compiled program -> [q_pad], the
+    padded rows exactly 0; a table already ``q_pad`` rows long is
+    estimated as it is.
+
+    The kernel path pads the table inside the program, where the kernel's
+    own layout pads it again (Q to its tile, Cmax, dim and the validity
+    column), so XLA can fuse the two and a device table is not copied
+    whole before the launch; the kernel sees what a host-padded table
+    gives it. The XLA path pads its estimates instead: a pad of its input
+    would be fused into the estimate's contraction and change its
+    rounding."""
+    def pad(x):
+        return jnp.pad(x, [(0, q_pad - x.shape[0])] + [(0, 0)] * (x.ndim - 1))
+    if use_kernels is None or use_kernels:
+        from repro.kernels.servicecost import _service_cost_jit
+        return _service_cost_jit(points, probs, member,
+                                 CostTable(*map(pad, table)), None, None)
+    return pad(_estimate_xla_jit(points, probs, member, table, None))
 
 
 @jax.jit

@@ -22,14 +22,19 @@ and the [Q, 128] accumulator is reduced to [Q] once at the end. Launch
 count is flat in both Q and Cmax. The Q-tile holds at most ``_ROWS``
 center rows, so the distance block's VMEM footprint is bounded whatever
 the batch size — callers never split Q. Q pads to the tile, Cmax to the
-sublane quantum (8), dim to 8 with one spare column, slots to 128.
+sublane quantum (8), dim to 8 with one spare column, slots to 128. The
+engine's pad of Q to its ``q_quantum`` bucket happens on the host for a
+host table (``core.costs.pad_cost_table``); for a table already on the
+device it happens in the same compiled program as this layout and the
+launch (``core.costs.estimate_bucketed``), so the table is not copied
+whole before the launch.
 
 Per-center validity rides that spare column of the center rows (1.0 =
 invalid slot: ragged sets, padded rows), so it arrives already laid out
 along the sublanes; the points' matching row is zero, so the flag never
 enters the contraction. Invalid slots are masked to +inf before the min,
-so an all-invalid row estimates exactly 0 (the ``pad_cost_table``
-padding element). Per-set parameters (mu, r, mode) ride one [Q, 128]
+so an all-invalid row estimates exactly 0 (the padding element of the
+Q bucket). Per-set parameters (mu, r, mode) ride one [Q, 128]
 row block, columns 0..2.
 
 Wire semantics are defined by ``core.costs.service_cost_values`` (the XLA

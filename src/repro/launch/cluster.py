@@ -17,7 +17,8 @@ device-RESIDENT sampled point slab:
     comparable across chunks (coordination under a fixed normalization).
 
 ``service_costs`` answers a Q-batch of candidate sets x the slab in ONE
-fused launch (Q bucketed to a quantum so jit traces stay bounded).
+compiled program (a host batch's Q bucketed to a quantum so jit traces
+stay bounded; a device-built table is padded inside the program).
 
 On top rides the paper's optimization meta-algorithm — compute a sample
 once, then optimize over estimated costs:
@@ -33,6 +34,7 @@ once, then optimize over estimated costs:
 """
 from __future__ import annotations
 
+from math import prod
 from typing import Callable, List, NamedTuple, Optional
 
 import jax
@@ -40,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.costs import (CostTable, ball_query, cost_table,
-                              encode_cost_queries, estimate_service_costs,
+                              encode_cost_queries, estimate_bucketed,
                               exact_service_costs, pad_cost_table,
                               swap_centers, swap_cost_table)
 from repro.core.funcs import SUM
@@ -272,42 +274,48 @@ class ClusterEngine:
         service-cost queries -> float numpy [Q]. ONE fused launch over the
         slab regardless of Q and Cmax (kernels.servicecost tiles Q inside
         the launch, so its VMEM footprint is bounded for any batch); Q pads
-        to ``q_quantum`` with null rows so same-bucket batches share one
-        compiled executable. A table whose fields are all device arrays
-        (``swap_cost_table``) is padded on the device and never crosses the
-        host; any other is encoded and padded on the host and copied over
-        by the launch.
+        to ``q_quantum`` with null rows. A table whose fields are all
+        device arrays (``swap_cost_table``) goes to the launch as it is and
+        is padded inside it, one compiled program per Q; any other is
+        encoded and padded on the host, so same-bucket batches share one
+        program, and is copied over by the launch.
 
         Under a profiler capture it records the spans ``cluster.score``
-        (the call), ``.prep`` (encode and pad), ``.dispatch`` (the
-        estimate's host-to-device hand-off and launch) and ``.wait`` (the
-        read-back, which blocks on the kernel), and the counters
-        ``cluster.score.sets`` (Q), ``.table_bytes`` (the padded table's
-        five fields, wherever they live) and ``.upload_bytes`` (those of
-        them copied from host memory; 0 for a device table)."""
+        (the call), ``.prep`` (the bucket size, and a host table's encoding
+        and padding), ``.dispatch`` (the launch, with a host table's copy
+        to the device) and ``.wait`` (the read-back, which blocks on the
+        kernel), and the counters ``cluster.score.sets`` (Q),
+        ``.table_bytes`` (the table's five fields padded to the bucket,
+        from their shapes) and ``.upload_bytes`` (those of them copied from
+        host memory; 0 for a device table)."""
         with trace.span("cluster.score"):
             with trace.span("cluster.score.prep"):
-                table, q = self._padded(queries)
+                table, q, q_pad = self._table(queries)
             with trace.span("cluster.score.dispatch"):
-                est = estimate_service_costs(
+                est = estimate_bucketed(
                     self._coords, self._sketch.probs, self._sketch.member,
-                    table, use_kernels=self.use_kernels)
+                    table, q_pad, self.use_kernels)
             with trace.span("cluster.score.wait"):
                 out = np.asarray(est)[:q]
         trace.count("cluster.score.sets", q)
         trace.count("cluster.score.table_bytes",
-                    lambda: sum(x.nbytes for x in table))
+                    lambda: q_pad * sum(x.dtype.itemsize * prod(x.shape[1:])
+                                        for x in table))
         trace.count("cluster.score.upload_bytes",
                     lambda: sum(x.nbytes for x in table
                                 if not isinstance(x, jax.Array)))
         return out
 
-    def _padded(self, queries):
-        """(the encoded table padded to its ``q_quantum`` bucket, Q)."""
+    def _table(self, queries):
+        """(the table to launch, Q, its ``q_quantum`` bucket): a table whose
+        fields are all device arrays as it is, any other encoded and padded
+        to the bucket on the host."""
         table = encode_cost_queries(queries)
         q = table.mu.shape[0]
-        qpad = max(self.q_quantum, -(-q // self.q_quantum) * self.q_quantum)
-        return pad_cost_table(table, qpad), q
+        q_pad = max(self.q_quantum, -(-q // self.q_quantum) * self.q_quantum)
+        if not all(isinstance(x, jax.Array) for x in table):
+            table = pad_cost_table(table, q_pad)
+        return table, q, q_pad
 
     def clustering_cost(self, centers, mu: Optional[float] = None) -> float:
         """Estimated Sum_x min_{c in centers} d(x,c)^mu for ONE set."""
@@ -375,9 +383,9 @@ def local_search(engine: ClusterEngine, k: int, mu: Optional[float] = None,
     the candidates, uploaded once a search, and the current set, uploaded
     once a round, so the engine scores it without a host copy; a round
     rebuilds only its centers (``swap_centers``). A search of no rounds
-    compiles what a round runs (``swap_centers``, the engine's pad, and the
-    read of one set's rows, as a scorer reads its sets), so callers warm
-    a centre count's shapes with it.
+    runs once what a round runs (``swap_centers``, the engine's one program
+    for the table, and the read of one set's rows, as a scorer reads its
+    sets), so callers warm a centre count's shapes with it.
 
     Under a profiler capture it records the spans ``cluster.search`` (the
     call), ``.seed`` (candidates, farthest-point init, first score),
@@ -402,8 +410,8 @@ def local_search(engine: ClusterEngine, k: int, mu: Optional[float] = None,
             table = swap_cost_table(jnp.asarray(cur), cand_dev, mu)
             if not rounds:
                 # a search of no rounds is how callers warm a centre
-                # count: compile the rest of what a round runs too
-                engine._padded(table)
+                # count: run the rest of what a round runs once too
+                engine.service_costs(table)
                 table.centers[0], table.cvalid[0]             # one set
             fields = table._replace(centers=None)
             del table

@@ -1,4 +1,5 @@
-"""Merge-dispatch spy: counts of the engine's merge-work dispatches.
+"""Dispatch spies: counts of the engine's merge-work dispatches, and of
+the calls through a module's jitted functions (``spy_jit_calls``).
 
 Pytest-free on purpose — the bench-smoke CI job imports this module from
 `benchmarks/run.py` (an environment with jax+numpy only) to assert the
@@ -17,9 +18,14 @@ full re-merge delegates, the inner fold is un-spied so it counts as one
 phase being measured (the query loop), not the whole run, to count
 query-time dispatches only.
 """
+import collections
 from contextlib import contextmanager
 
+import jax
+
 from repro.launch import query as Q
+
+_JITTED = type(jax.jit(abs))
 
 
 @contextmanager
@@ -49,3 +55,29 @@ def spy_merge_dispatch():
     finally:
         Q._full_remerge = real_full
         Q.multisketch_absorb_slabs = real_into
+
+
+@contextmanager
+def spy_jit_calls(*modules):
+    """Context manager yielding a live ``{"<module>.<name>": n}`` count of
+    the calls made, while the context is active, through every jitted
+    function that ``modules`` hold as attributes. A call of a compiled
+    program from inside another is not a call through the attribute, so
+    a warm launch counts once."""
+    counts = collections.Counter()
+    real = [(mod, name, fn) for mod in modules
+            for name, fn in vars(mod).items() if isinstance(fn, _JITTED)]
+
+    def spy(key, fn):
+        def call(*a, **k):
+            counts[key] += 1
+            return fn(*a, **k)
+        return call
+
+    for mod, name, fn in real:
+        setattr(mod, name, spy(f"{mod.__name__}.{name}", fn))
+    try:
+        yield counts
+    finally:
+        for mod, name, fn in real:
+            setattr(mod, name, fn)

@@ -9,8 +9,8 @@ import pytest
 
 import repro.core as C
 from repro.core.costs import (CostTable, ball_query, cost_query, cost_table,
-                              encode_cost_queries, pad_cost_table,
-                              swap_cost_table)
+                              encode_cost_queries, estimate_bucketed,
+                              pad_cost_table, swap_cost_table)
 from repro.kernels import ref as R
 from repro.kernels.servicecost import service_cost_slab
 from repro.launch.cluster import (ClusterEngine, exact_scorer, kcenter,
@@ -306,20 +306,23 @@ def test_local_search_device_tables_match_host_tables(k, n_cand, slab):
     assert a.history == b.history and a.rounds == b.rounds == 4
 
 
+@pytest.mark.parametrize("k,n", [(2, 7), (3, 5), (4, 4)])
 @pytest.mark.parametrize("uk", [True, False])
-def test_service_costs_device_table_matches_host_table(uk, tmp_path):
+def test_service_costs_device_table_matches_host_table(uk, k, n, tmp_path):
     """A device table scores bit for bit as the same table in numpy, on the
-    kernel and the XLA path, with Q off the quantum; only the host table's
-    fields are counted as uploaded."""
+    kernel and the XLA path, with Q just below, at and just above a bucket
+    edge; the answer holds Q rows, none of the bucket's null rows; only
+    the host table's fields are counted as uploaded, and both count the
+    table as padded to the bucket."""
     from repro.telemetry import trace
     eng = ClusterEngine.fit(_points(dim=5), k=48, mu=2.0, seed=3,
                             q_quantum=16, use_kernels=uk)
     rng = np.random.default_rng(7)
     dev = swap_cost_table(
-        jnp.asarray(rng.normal(0, 3, (3, 5)).astype(np.float32)),
-        jnp.asarray(rng.normal(0, 3, (7, 5)).astype(np.float32)), 2.0)
+        jnp.asarray(rng.normal(0, 3, (k, 5)).astype(np.float32)),
+        jnp.asarray(rng.normal(0, 3, (n, 5)).astype(np.float32)), 2.0)
     host = CostTable(*(np.asarray(x) for x in dev))
-    assert host.mu.shape[0] % 16 != 0
+    q = 1 + k * n
     outs, counters = [], []
     trace.reset()
     try:
@@ -330,24 +333,49 @@ def test_service_costs_device_table_matches_host_table(uk, tmp_path):
             trace.reset()
     finally:
         trace.reset()
-    assert outs[0].shape == (22,)
+    assert outs[0].shape == outs[1].shape == (q,)
     np.testing.assert_array_equal(outs[0], outs[1])
-    padded = sum(x.nbytes for x in pad_cost_table(host, 32))
+    assert np.all(outs[0] > 0)
+    padded = sum(x.nbytes for x in pad_cost_table(host, -(-q // 16) * 16))
     assert counters[0]["cluster.score.upload_bytes"] == 0
     assert counters[1]["cluster.score.upload_bytes"] == padded
     assert (counters[0]["cluster.score.table_bytes"]
             == counters[1]["cluster.score.table_bytes"] == padded)
 
 
-def test_pad_cost_table_device_rows_match_host_rows():
+@pytest.mark.parametrize("uk", [True, False])
+def test_in_program_pad_rows_estimate_exactly_zero(uk):
+    """The null rows a device table is padded with inside the launch
+    estimate exactly 0, and its own rows are the host-padded table's."""
+    eng = _engine("ppswor")
+    pts, probs, member = eng.sample()
     t = swap_cost_table(jnp.ones((2, 3), jnp.float32),
-                        jnp.zeros((4, 3), jnp.float32), 1.0)
-    dev = pad_cost_table(t, 16)
-    host = pad_cost_table(CostTable(*(np.asarray(x) for x in t)), 16)
-    for d, h in zip(dev, host):
-        assert isinstance(d, jax.Array) and isinstance(h, np.ndarray)
-        assert np.asarray(d).tobytes() == h.tobytes()
-    assert pad_cost_table(t, 8) is t
+                        jnp.zeros((4, 3), jnp.float32), 1.0)     # Q = 9
+    got = np.asarray(estimate_bucketed(pts, probs, member, t, 16, uk))
+    want = np.asarray(C.estimate_service_costs(
+        pts, probs, member, pad_cost_table(t, 16), use_kernels=uk))
+    assert got.shape == (16,)
+    np.testing.assert_array_equal(got[9:], np.zeros(7, np.float32))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("uk", [True, False])
+def test_service_costs_launches_one_program_for_a_device_table(uk):
+    """Scoring a device table is one jitted launch: no separate pad, no
+    second program around the estimate."""
+    from repro.core import costs
+    from repro.kernels import servicecost
+    from repro.launch import cluster
+    from tests.dispatch_spy import spy_jit_calls
+    eng = ClusterEngine.fit(_points(dim=5), k=48, mu=2.0, seed=3,
+                            q_quantum=16, use_kernels=uk)
+    dev = swap_cost_table(jnp.ones((3, 5), jnp.float32),
+                          jnp.zeros((7, 5), jnp.float32), 2.0)
+    want = eng.service_costs(dev)                     # compiles
+    with spy_jit_calls(costs, servicecost, cluster) as calls:
+        got = eng.service_costs(dev)
+    assert calls == {"repro.launch.cluster.estimate_bucketed": 1}
+    np.testing.assert_array_equal(got, want)
 
 
 # ------------------------------------------------ optimizer vs exact oracle

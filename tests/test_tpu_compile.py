@@ -115,3 +115,22 @@ def test_service_cost_compiles(one_chip):
              ((CLUSTER_CAP,), jnp.bool_), ((Q, CMAX, DIM), jnp.float32),
              ((Q, CMAX), jnp.bool_), ((Q,), jnp.float32),
              ((Q,), jnp.float32), ((Q,), jnp.int32))
+
+
+def test_bucketed_service_cost_compiles(one_chip, monkeypatch):
+    """The one program that pads a device-built swap round's table to its
+    bucket and scores it: the widest round the search benchmark runs
+    (k = 50 centres, 64 candidates, d = 68)."""
+    from repro.core.costs import estimate_bucketed
+    from repro.kernels import servicecost
+    # this process's backend is the CPU; compile the chip's kernel
+    monkeypatch.setattr(servicecost, "resolve_interpret", lambda i: False)
+    k, d = 50, 68
+    q = 1 + 64 * k
+    _compile(one_chip,
+             lambda p, pr, m, c, cv, mu, r, mode: estimate_bucketed(
+                 p, pr, m, CostTable(c, cv, mu, r, mode), q + 15, None),
+             ((CLUSTER_CAP, d), jnp.float32), ((CLUSTER_CAP,), jnp.float32),
+             ((CLUSTER_CAP,), jnp.bool_), ((q, k, d), jnp.float32),
+             ((q, k), jnp.bool_), ((q,), jnp.float32), ((q,), jnp.float32),
+             ((q,), jnp.int32))

@@ -118,6 +118,22 @@ def test_a_search_warmed_as_the_benchmark_warms_it_traces_nothing(tmp_path):
     assert trace.snapshot().counters.get("jax.traces", 0) == 0
 
 
+@pytest.mark.parametrize("uk", [True, False])
+def test_a_search_of_no_rounds_warms_the_rounds(uk, tmp_path):
+    """A search of no rounds leaves nothing for the rounds of a search at
+    the same centre count to trace: the round's one program for its
+    device table, its centers and the read of a set's rows."""
+    eng = ClusterEngine.fit(_points(dim=6, seed=4), k=40, mu=2.0, seed=5,
+                            q_quantum=16, use_kernels=uk)
+    local_search(eng, 4, rounds=0, n_cand=8)
+    with jax.profiler.trace(str(tmp_path)):
+        res = local_search(eng, 4, rounds=2, n_cand=8, tol=-1.0,
+                           scorer=lambda t: (t.centers[0], t.cvalid[0],
+                                             eng.service_costs(t))[-1])
+    assert res.rounds == 2
+    assert trace.snapshot().counters.get("jax.traces", 0) == 0
+
+
 def test_spans_are_written_into_the_profilers_trace(engine, tmp_path):
     from jax.profiler import ProfileData
     with jax.profiler.trace(str(tmp_path)):
