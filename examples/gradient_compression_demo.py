@@ -52,5 +52,4 @@ if __name__ == "__main__":
         print(f"{i:4d} | {d:10.4f} | {s:10.4f}")
     wire = 3 * 256 * 12  # 3k slots x (idx,val,prob) per big leaf
     print(f"\ncross-pod bytes per big leaf: dense = leaf_size*4, "
-          f"sampled = {wire} (fixed) — see EXPERIMENTS.md §Perf for the "
-          f"production-mesh collective-term numbers")
+          f"sampled = {wire} (fixed)")

@@ -1,6 +1,6 @@
 """hubert-xlarge [audio] — 48L d_model=1280 16H d_ff=5120 vocab=504,
 encoder-only (w2v2 arch). Modality frontend (conv feature extractor) is a
-STUB per spec: input_specs() provides precomputed frame embeddings.
+STUB per spec: the batch carries precomputed frame embeddings.
 [arXiv:2106.07447; unverified]"""
 from repro.models.config import ModelConfig
 

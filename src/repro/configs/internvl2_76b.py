@@ -1,6 +1,6 @@
 """internvl2-76b [vlm] — 80L d_model=8192 64H (GQA kv=8) d_ff=28672
 vocab=128256 (InternViT + InternLM2 backbone). Per spec the ViT frontend is a
-STUB: input_specs() provides precomputed patch embeddings; we model the
+STUB: the batch carries precomputed patch embeddings; we model the
 LLM backbone over [patches | text]. [arXiv:2404.16821; unverified]"""
 from repro.models.config import ModelConfig
 

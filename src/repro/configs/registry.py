@@ -1,4 +1,4 @@
-"""Architecture registry: ``--arch <id>`` -> ModelConfig (+ smoke config)."""
+"""Architecture registry: arch id -> ModelConfig (+ smoke config)."""
 from __future__ import annotations
 
 import importlib
@@ -34,8 +34,3 @@ def get_smoke_config(arch_id: str):
 def list_archs():
     return list(ARCHS)
 
-
-def sub_quadratic(cfg) -> bool:
-    """True if decode/long-context cost per token is sub-quadratic-safe
-    (SSM / hybrid families; paper-spec gate for the long_500k shape)."""
-    return cfg.family in ("ssm", "hybrid")

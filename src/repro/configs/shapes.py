@@ -1,7 +1,7 @@
 """Assigned input-shape set (LM transformer shapes: seq_len x global_batch).
 
-``decode_*`` / ``long_*`` lower ``serve_step`` (one new token against a KV
-cache of seq_len); the others lower ``train_step``.
+``decode_*`` / ``long_*`` are one new token against a KV cache of seq_len;
+the others are full sequences.
 """
 from __future__ import annotations
 

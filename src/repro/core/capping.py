@@ -11,7 +11,7 @@ belong to keys with h_y + l_y <= k (Lemma 6.1/6.4 argument: a key's seed rank
 is minimized at T = w_y), so the final pass may be restricted to the small
 candidate set {h + l <= k} — this is the paper's §6.1 algorithm.
 
-Size (Thm 6.1): E|S^(C,k)| <= e k ln(w_max/w_min) — verified in benchmarks.
+Size (Thm 6.1): E|S^(C,k)| <= e k ln(w_max/w_min) — checked in tests.
 
 Production path = two sort+buffer scans (h-scan by (-w, u); l-scan by (w, rw))
 + an O(m^2) pairwise pass on the m candidate keys (m is a static capacity;

@@ -11,7 +11,7 @@ Key facts implemented here:
                   inclusive set, so deleting it shifts k-th -> (k+1)-th.
   Aux keys Z:     the keys realizing those (k+1)-th smallest values for at
                   least one member's weight group, minus S (paper §5).
-  Size bound:     E|S^(M,k)| <= k ln n (Thm 5.1) — verified in benchmarks.
+  Size bound:     E|S^(M,k)| <= k ln n (Thm 5.1) — checked in tests.
 
 Two implementations:
   * ``universal_monotone_ref``  — O(n^2) pairwise oracle (tests, small n).

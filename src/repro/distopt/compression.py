@@ -90,7 +90,7 @@ def _sample_leaf(g, k: int, seed, cap_frac: float,
 
 def _merge_leaf(idx, val, prob, valid, n, npods):
     """HT-estimate the mean gradient from gathered per-pod sketch slabs
-    (all-sampled variant; benchmarks use this single-pod)."""
+    (all-sampled variant)."""
     contrib = jnp.where(valid, val / jnp.maximum(prob, 1e-30), 0.0)
     dense = jnp.zeros((n,), jnp.float32)
     dense = dense.at[jnp.maximum(idx, 0).reshape(-1)].add(contrib.reshape(-1))

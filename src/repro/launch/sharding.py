@@ -3,8 +3,8 @@
 Model code declares per-dimension LOGICAL axes ("embed", "q_heads", "mlp",
 "vocab", "expert", "inner", ...). This module maps them to physical mesh axes
 with divisibility gating: a dimension is sharded on "model" only when its
-size divides evenly — otherwise it is replicated (recorded for the roofline
-notes; XLA padding of uneven shards is avoided by construction).
+size divides evenly — otherwise it is replicated (XLA padding of uneven
+shards is avoided by construction).
 """
 from __future__ import annotations
 
@@ -18,10 +18,6 @@ _MODEL_AXES = ("q_heads", "kv_heads", "mlp", "vocab", "expert", "inner")
 def _is_spec(x):
     return isinstance(x, tuple) and all(isinstance(a, (str, type(None)))
                                         for a in x)
-
-
-def map_spec_tree(fn, spec_tree):
-    return jax.tree.map(fn, spec_tree, is_leaf=_is_spec)
 
 
 def logical_to_pspec(spec: tuple, shape: tuple, mesh, fsdp: bool = False) -> P:
@@ -71,58 +67,6 @@ def batch_pspec(mesh) -> P:
     return P("data")
 
 
-def _nshards(mesh, axis) -> int:
-    if isinstance(axis, tuple):
-        n = 1
-        for a in axis:
-            n *= mesh.shape[a]
-        return n
-    return mesh.shape[axis]
-
-
-def batch_shardings(batch_tree, mesh):
-    """Shard every batch leaf on its leading (batch) dimension; replicate
-    when the batch does not divide the data axes (e.g. long_500k B=1)."""
-    baxis = batch_pspec(mesh)[0]
-    n = _nshards(mesh, baxis)
-
-    def one(leaf):
-        extra = max(leaf.ndim - 1, 0)
-        lead = baxis if leaf.shape[0] % n == 0 else None
-        return NamedSharding(mesh, P(*([lead] + [None] * extra)))
-    return jax.tree.map(one, batch_tree)
-
-
 def replicated(mesh):
     return NamedSharding(mesh, P())
 
-
-def cache_shardings(cache_tree, cfg, mesh):
-    """Decode caches: batch dim on (pod?,data); head/channel dims on model
-    where divisible. Cache layouts:
-      dense kv:   [L, B, S, K, hd]   -> (None, batch, None, model?, None)
-      hybrid kv:  [G, B, S, K, hd]   -> same
-      mamba conv: [L, B, K-1, C]     -> (None, batch, None, model?)
-      mamba h:    [L, B, di, N] / [L, B, H, hd, N]
-    """
-    b = batch_pspec(mesh)[0]
-    nb = _nshards(mesh, b)
-    msize = mesh.shape["model"]
-
-    def one(leaf):
-        dims = list(leaf.shape)
-        axes = [None] * leaf.ndim
-        if leaf.ndim >= 2 and dims[1] % nb == 0:
-            axes[1] = b  # batch is dim 1 (stacked layers lead)
-        # shard the LARGEST DIVISIBLE remaining dim on model (seq for kv
-        # caches -> sequence-parallel decode attention; channels for ssm)
-        cand = sorted(((d, i) for i, d in enumerate(dims[2:], start=2)),
-                      reverse=True)
-        for d, i in cand:
-            if d % msize == 0 and d >= msize:
-                axes[i] = "model"
-                break
-        while axes and axes[-1] is None:
-            axes.pop()
-        return NamedSharding(mesh, P(*axes))
-    return jax.tree.map(one, cache_tree)

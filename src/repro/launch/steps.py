@@ -1,13 +1,11 @@
-"""Jitted, sharded step functions + abstract input/state builders.
+"""The jitted, sharded train step and the abstract state it shards.
 
-Everything here works both with concrete arrays (training on real devices)
-and with ShapeDtypeStructs through .lower()/.compile() (the multi-pod
-dry-run) — no device allocation happens at build time.
+The state's shardings come from ShapeDtypeStructs (``jax.eval_shape``),
+so no device allocation happens at build time.
 """
 from __future__ import annotations
 
-from functools import partial
-from typing import Any, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,31 +16,12 @@ from repro.core.multi_sketch import (MultiSketchSpec,
 from repro.models import model as Mod
 from repro.models.config import ModelConfig
 from repro.optim import adamw
-from repro.configs.shapes import ShapeConfig
 from . import sharding as Sh
 
 
 # ---------------------------------------------------------------------------
-# abstract inputs (ShapeDtypeStruct stand-ins — never allocated)
+# abstract state (ShapeDtypeStruct stand-ins — never allocated)
 # ---------------------------------------------------------------------------
-
-def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict[str, Any]:
-    """ShapeDtypeStruct batch for (arch x shape). Train/prefill kinds give the
-    full-sequence batch; decode kinds give the per-step token batch."""
-    B, S = shape.global_batch, shape.seq_len
-    f = jax.ShapeDtypeStruct
-    if shape.kind in ("train", "prefill"):
-        if cfg.family == "encoder":
-            return {"frames": f((B, S, cfg.d_model), jnp.bfloat16),
-                    "labels": f((B, S), jnp.int32)}
-        if cfg.family == "vlm":
-            Ptok = cfg.frontend_tokens
-            return {"tokens": f((B, S - Ptok), jnp.int32),
-                    "patches": f((B, Ptok, cfg.d_model), jnp.bfloat16)}
-        return {"tokens": f((B, S), jnp.int32)}
-    # decode: one new token against a cache of length S
-    return {"tokens": f((B,), jnp.int32)}
-
 
 def abstract_params(cfg: ModelConfig):
     """(param ShapeDtypeStructs, logical spec tree) without allocation.
@@ -79,19 +58,13 @@ def state_shardings(cfg: ModelConfig, mesh):
     }, state_shapes
 
 
-def cache_abstract(cfg: ModelConfig, shape: ShapeConfig):
-    return jax.eval_shape(
-        lambda: Mod.make_cache(cfg, shape.global_batch, shape.seq_len))
-
-
 # ---------------------------------------------------------------------------
 # step factories
 # ---------------------------------------------------------------------------
 
 def make_train_step(cfg: ModelConfig, opt_cfg: adamw.OptConfig, mesh,
                     grad_transform=None, microbatch: Optional[int] = None,
-                    donate: bool = True, shape: Optional[ShapeConfig] = None,
-                    compress: Optional[dict] = None,
+                    donate: bool = True, compress: Optional[dict] = None,
                     telemetry: Optional[MultiSketchSpec] = None):
     """Returns (jitted_step, state_shardings_tree).
 
@@ -114,8 +87,6 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.OptConfig, mesh,
         rep = Sh.replicated(mesh)
         st_shard["tel"] = jax.tree.map(lambda _: rep,
                                        multisketch_shape(telemetry))
-    batch_sh = (Sh.batch_shardings(input_specs(cfg, shape), mesh)
-                if shape is not None else None)
 
     def loss_of(params, batch):
         return Mod.loss_fn(params, cfg, batch)
@@ -192,44 +163,7 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.OptConfig, mesh,
 
     jitted = jax.jit(
         step_fn,
-        in_shardings=(st_shard, batch_sh),
+        in_shardings=(st_shard, None),
         out_shardings=(st_shard, None),
         donate_argnums=(0,) if donate else ())
     return jitted, st_shard
-
-
-def make_prefill_step(cfg: ModelConfig, mesh,
-                      shape: Optional[ShapeConfig] = None):
-    def step_fn(params, batch):
-        return Mod.prefill(params, cfg, batch)
-
-    p_shapes, specs = abstract_params(cfg)
-    psh = Sh.param_shardings(specs, p_shapes, mesh, fsdp=cfg.fsdp)
-    batch_sh = (Sh.batch_shardings(input_specs(cfg, shape), mesh)
-                if shape is not None else None)
-    cache_sh = (Sh.cache_shardings(
-        jax.eval_shape(lambda: Mod.make_cache(
-            cfg, shape.global_batch, shape.seq_len)), cfg, mesh)
-        if shape is not None and cfg.family != "encoder" else None)
-    out_sh = (None, cache_sh) if cache_sh is not None else None
-    return jax.jit(step_fn, in_shardings=(psh, batch_sh),
-                   out_shardings=out_sh), psh
-
-
-def make_serve_step(cfg: ModelConfig, shape: ShapeConfig, mesh,
-                    donate: bool = True):
-    """Single-token decode step against a seq_len cache."""
-    p_shapes, specs = abstract_params(cfg)
-    psh = Sh.param_shardings(specs, p_shapes, mesh, fsdp=cfg.fsdp)
-    cache_sh = Sh.cache_shardings(cache_abstract(cfg, shape), cfg, mesh)
-
-    def step_fn(params, tokens, cache, index):
-        return Mod.serve_step(params, cfg, tokens, cache, index)
-
-    jitted = jax.jit(
-        step_fn,
-        in_shardings=(psh, Sh.batch_shardings(
-            input_specs(cfg, shape)["tokens"], mesh), cache_sh, None),
-        out_shardings=(None, cache_sh),
-        donate_argnums=(2,) if donate else ())
-    return jitted, psh, cache_sh

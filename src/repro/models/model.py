@@ -239,24 +239,6 @@ def make_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=ACT_DTYPE):
     raise ValueError(f"{cfg.family} has no decode step")
 
 
-def grow_cache(cfg: ModelConfig, cache, extra: int):
-    """Extend a prefill cache's time axis by ``extra`` decode slots.
-
-    Attention k/v leaves (dense/moe/vlm and the hybrid family's attention
-    groups) have layout [groups, B, T, H, D]; SSM state leaves carry no time
-    axis and pass through unchanged.
-    """
-    if extra <= 0 or not isinstance(cache, dict):
-        return cache
-    grown = dict(cache)
-    for name in ("k", "v"):
-        if name in grown:
-            pad = [(0, 0)] * grown[name].ndim
-            pad[2] = (0, extra)
-            grown[name] = jnp.pad(grown[name], pad)
-    return grown
-
-
 def serve_step(params, cfg: ModelConfig, tokens, cache, index):
     """One decode step. tokens: [B] int32; index: current length (scalar).
 

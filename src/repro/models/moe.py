@@ -5,7 +5,7 @@ Dispatch is capacity-based scatter/gather (GShard-style semantics) WITHOUT the
 of expert one-hots (local to each batch row, so no cross-device cumsum), and
 tokens move via batched scatter/gather. Expert weights are sharded on the
 "expert" logical axis (-> mesh "model"); the data->expert redistribution is
-what surfaces as all-to-all / collective traffic in the dry-run HLO.
+what surfaces as all-to-all / collective traffic in the compiled HLO.
 
 FLOPs are proportional to ACTIVE params (top-k + shared), matching the MoE
 roofline convention MODEL_FLOPS = 6 * N_active * D.

@@ -1,11 +1,10 @@
 """Dispatch spies: counts of the engine's merge-work dispatches, and of
 the calls through a module's jitted functions (``spy_jit_calls``).
 
-Pytest-free on purpose — the bench-smoke CI job imports this module from
-`benchmarks/run.py` (an environment with jax+numpy only) to assert the
-zero-merge serving claim: a query phase under absorb-time maintenance
-must dispatch NO merge work (``full == inc == 0``), because every chunk
-was already folded into the cached merged slab at absorb time.
+A test helper: the engine tests use it to assert the zero-merge
+serving claim. A query phase under absorb-time maintenance must dispatch
+NO merge work (``full == inc == 0``), because every chunk was already
+folded into the cached merged slab at absorb time.
 
 The spy wraps the two query-path merge entry points in ``launch.query``:
 ``_full_remerge`` (the full stacked re-merge, itself a fold into a fresh

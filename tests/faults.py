@@ -1,7 +1,8 @@
 """Fault-injection chaos harness for the serving tier.
 
-Shared by the fault-tolerance test tier (tests/test_serving_faults.py) and
-the ``serving_chaos`` benchmark (benchmarks/run.py). Three layers:
+Used by the fault-tolerance tests (tests/test_serving_faults.py,
+tests/test_pool_scaleout.py, tests/test_checkpoint.py and others). Three
+layers:
 
   * :class:`FaultInjector` — deterministic failure schedules installed on
     the ``EnginePool`` fault points (launch.pool.FAULT_POINTS): fail the
